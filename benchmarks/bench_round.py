@@ -520,9 +520,17 @@ ENGINE_DEFAULTS = {"ks": [50, 200, 500], "rounds": 3, "seeds": 3,
                    "engines": ["loop", "vectorized"], "buckets": 3}
 
 
+# every worker starts here: the persistent compile cache, placed from
+# outside through JAX_COMPILATION_CACHE_DIR or else at <checkout>/.jax_cache
+_WORKER_PREAMBLE = (
+    "from repro.launch.compile_cache import use_compile_cache\n"
+    "use_compile_cache()\n")
+
+
 def _run_worker(code, argv, timeout=3600, extra_env=None):
     r = subprocess.run(
-        [sys.executable, "-c", code] + [str(a) for a in argv],
+        [sys.executable, "-c", _WORKER_PREAMBLE + code]
+        + [str(a) for a in argv],
         capture_output=True, text=True,
         env={**os.environ,
              "PYTHONPATH": SRC + os.pathsep + os.environ.get("PYTHONPATH",
@@ -755,50 +763,27 @@ def bench_async(k=16, n_train=8000, n_test=800, rounds=8,
 
 _POPULATION_WORKER = r"""
 import json, sys, time
+import jax
 import numpy as np
-from repro.configs.base import FeelConfig
 from repro.core import control as ctl
 from repro.core import population as pop
-from repro.core.scheduler import POLICY_IDS
-from repro.core.wireless import WirelessModel
 
 mode, n, k, n_runs, rounds = (sys.argv[1], int(sys.argv[2]),
                               int(sys.argv[3]), int(sys.argv[4]),
                               int(sys.argv[5]))
-cfg = FeelConfig(n_ues=k, n_malicious=max(k // 10, 1), population=n)
-rng = np.random.default_rng(0)
-policies = [list(POLICY_IDS)[i % len(POLICY_IDS)] for i in range(n_runs)]
-wm = WirelessModel(cfg, np.random.default_rng(1))
-sizes = (rng.integers(1, 31, (n_runs, n)) * 50).astype(float)
-cpu = rng.uniform(cfg.cpu_hz_min, cfg.cpu_hz_max, (n_runs, n))
-state = ctl.ControlState(
-    policy_id=np.array([POLICY_IDS[p] for p in policies], np.int32),
-    sizes=sizes, divs=rng.uniform(0.0, 0.9, (n_runs, n)),
-    r_min=np.stack([wm.min_rate(wm.train_time(sizes[i], cpu[i]))
-                    for i in range(n_runs)]),
-    reputations=rng.uniform(0.0, 1.0, (n_runs, n)),
-    ages=np.ones((n_runs, n)), cfg=cfg)
-omega = np.full(n_runs, cfg.omega_rep), np.full(n_runs, cfg.omega_div)
-
-def draw(t):
-    g = np.stack([wm.rng.exponential(1.0, n) * wm.distances
-                  ** (-cfg.pathloss_exp) for _ in range(n_runs)])
-    rr = np.stack([np.argsort(np.random.default_rng((t, i)).permutation(n))
-                   for i in range(n_runs)])
-    return g, rr
+state, omega, draw = pop.synthetic_population(n, k, n_runs)
+cfg = state.cfg
 
 if mode == "mesh":
     # exact N-wide schedule_runs on the forced multi-device host mesh:
     # hybrid (host numpy, cannot shard) vs the jitted jax kernel with the
     # population axis GSPMD-sharded over the mesh data axes — the
     # measurement behind default_kernel()'s multi-device "jax" choice
-    import jax
-    from jax.experimental import enable_x64
     mesh = pop.population_mesh()
     n_dev = len(jax.devices())
 
     def jax_round(g, rr):
-        with enable_x64():
+        with jax.enable_x64(True):
             ops = pop.shard_population(
                 mesh, state.reputations, state.ages, state.divs,
                 state.sizes, state.r_min, g, rr)
@@ -860,7 +845,6 @@ else:
     # visit-order sort + budget pack, O(N log N + N) exact vs
     # O(N) argpartition + O(M log M + M) prefiltered. Timed here on
     # precomputed dqs keys/costs (key choice does not change sort cost).
-    from jax.experimental import enable_x64
     from repro.core.diversity import diversity_index_rows
     from repro.core.quality import data_quality_value
     g, _ = draw(rounds + 1)
@@ -869,7 +853,7 @@ else:
     values = data_quality_value(state.reputations, I, cfg,
                                 omega=(omega[0][:, None],
                                        omega[1][:, None]))
-    with enable_x64():
+    with jax.enable_x64(True):
         costs = np.asarray(ctl._cost_kernel(
             g, state.r_min, cfg.bandwidth_hz, cfg.p_watt,
             cfg.n0_watt_hz, k=k)).astype(np.int32)

@@ -36,8 +36,8 @@ nondeterminism
 
 dtype-f64
     Device-side float64 belongs to the control plane only and always
-    under ``jax.experimental.enable_x64`` — a ``jnp.float64``
-    reference outside a ``with enable_x64():`` block either fails at
+    under ``jax.enable_x64(True)`` — a ``jnp.float64`` reference
+    outside a ``with jax.enable_x64(True):`` block either fails at
     runtime (x64 disabled) or silently forks the f32 data plane.
 
 masked-mean-pin
@@ -323,7 +323,7 @@ def lint_wall_clock(src: SourceFile) -> List[Violation]:
 # dtype-f64 / masked-mean-pin
 # --------------------------------------------------------------------- #
 def _x64_ranges(tree: ast.Module) -> List[Tuple[int, int]]:
-    """(start, end) line ranges of ``with enable_x64():`` blocks."""
+    """(start, end) line ranges of ``with jax.enable_x64(True):`` blocks."""
     out = []
     for node in ast.walk(tree):
         if not isinstance(node, ast.With):
@@ -349,7 +349,7 @@ def lint_dtype_f64(src: SourceFile) -> List[Violation]:
                 and node.value.id in jnp_names:
             if not any(a <= node.lineno <= b for a, b in ranges):
                 _violate(out, src, "dtype-f64", node.lineno,
-                         "`jnp.float64` outside a `with enable_x64():` "
+                         "`jnp.float64` outside a `with jax.enable_x64(True):` "
                          "block — device f64 is control-plane only and "
                          "must be x64-scoped (DESIGN.md §11)")
     return out

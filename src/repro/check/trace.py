@@ -6,7 +6,7 @@ is additionally enforced on the *jaxprs* of the key entry points:
 trace-f64
     The f32 data-plane programs — ``cohort_train``, ``cohort_eval``,
     ``fedavg_stacked``, the trimmed-mean/median defended aggregation,
-    ``ModelAttack.apply_stacked`` — are traced UNDER ``enable_x64()``
+    ``ModelAttack.apply_stacked`` — are traced UNDER ``jax.enable_x64(True)``
     (so any stray literal f64 promotion becomes visible instead of
     being silently squashed to f32) with explicitly f32-dtyped inputs,
     and their jaxprs must contain no float64 value and no
@@ -89,9 +89,8 @@ def assert_no_f64(name: str, trace_fn: Callable[[], object]
     every f64 site. Self-test entry point: any f32 program can be
     checked through this."""
     import jax
-    from jax.experimental import enable_x64
     try:
-        with enable_x64():
+        with jax.enable_x64(True):
             jaxpr = trace_fn()
     except Exception as e:                          # noqa: BLE001
         return [Violation(rule="trace-error", path=name, line=0,
@@ -106,9 +105,8 @@ def assert_no_f64(name: str, trace_fn: Callable[[], object]
 def assert_f64_outputs(name: str, trace_fn: Callable[[], object]
                        ) -> List[Violation]:
     import jax
-    from jax.experimental import enable_x64
     try:
-        with enable_x64():
+        with jax.enable_x64(True):
             jaxpr = trace_fn()
     except Exception as e:                          # noqa: BLE001
         return [Violation(rule="trace-error", path=name, line=0,

@@ -60,7 +60,6 @@ from typing import List, Optional, Sequence
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental import enable_x64
 
 from repro.configs.base import FeelConfig
 from repro.core.diversity import diversity_index_eq2, diversity_index_rows
@@ -221,7 +220,7 @@ def _schedule_hybrid(state: ControlState, gains, rand_rank, w_rep, w_div):
                                 omega=(w_rep[:, None], w_div[:, None]))
 
     # Eq. 9 — jitted bisection (XLA's f64 log2 beats numpy's ~3x here)
-    with enable_x64():
+    with jax.enable_x64(True):
         costs = np.asarray(_cost_kernel(
             gains, state.r_min, cfg.bandwidth_hz, cfg.p_watt,
             cfg.n0_watt_hz, k=K)).astype(int)
@@ -332,7 +331,7 @@ def schedule_runs(state: ControlState, gains: np.ndarray,
         if kern == "hybrid":
             return _schedule_hybrid(state, gains, rand_rank, w_rep, w_div)
         cfg = state.cfg
-        with enable_x64():
+        with jax.enable_x64(True):
             x, alpha, costs, values, forced = _schedule_kernel(
                 state.policy_id, state.reputations, state.ages, state.divs,
                 state.sizes, state.r_min, gains, rand_rank, w_rep, w_div,
@@ -397,7 +396,7 @@ def finalize_runs(state: ControlState, sels: List[np.ndarray],
             state.reputations = np.where(mask > 0, new, state.reputations)
             state.ages = np.where(mask > 0, 1.0, state.ages + 1.0)
             return
-        with enable_x64():
+        with jax.enable_x64(True):
             rep, ages = _finalize_kernel(
                 state.reputations, state.ages, mask, al, at, pen,
                 cfg.eta, cfg.beta1, cfg.beta2)

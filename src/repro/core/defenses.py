@@ -56,7 +56,6 @@ from typing import Dict, List, Optional, Tuple, Union
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental import enable_x64
 
 
 # ---------------------------------------------------------------------- #
@@ -260,7 +259,7 @@ class NormClip:
     def clip_batched(self, flat: jnp.ndarray, g: jnp.ndarray, n: int
                      ) -> Tuple[jnp.ndarray, DefenseStats]:
         delta = flat - g[None]
-        with enable_x64():
+        with jax.enable_x64(True):
             n2 = jnp.sum(delta.astype(jnp.float64) ** 2, axis=1)
             s64 = jnp.minimum(1.0,
                               self.tau / jnp.maximum(jnp.sqrt(n2), 1e-12))
@@ -314,7 +313,7 @@ class Krum:
         f, m = self._resolve(n, n_byz)
         if n - f - 2 < 1:
             return np.arange(n)
-        with enable_x64():
+        with jax.enable_x64(True):
             X = flat[:n].astype(jnp.float64)
             sq = jnp.einsum("ij,ij->i", X, X)
             d2 = jnp.maximum(sq[:, None] + sq[None, :] - 2.0 * (X @ X.T),

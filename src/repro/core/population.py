@@ -71,15 +71,14 @@ from typing import List, Optional, Sequence
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental import enable_x64
 from jax.sharding import PartitionSpec
 
 from repro.configs.base import FeelConfig
 from repro.core import control as ctl
 from repro.core.diversity import diversity_index_eq2, diversity_index_rows
 from repro.core.quality import data_quality_value
-from repro.core.scheduler import pack_scan, priority_key
-from repro.core.wireless import cost_bisect
+from repro.core.scheduler import POLICY_IDS, pack_scan, priority_key
+from repro.core.wireless import WirelessModel, cost_bisect
 from repro.launch.mesh import make_host_mesh
 from repro.obs import trace
 from repro.sharding.specs import data_axes, named
@@ -331,7 +330,7 @@ def prefilter_schedule_runs(state: ctl.ControlState, gains, rand_rank,
         if kern == "jax":
             ops = [state.reputations, state.ages, state.divs, state.sizes,
                    state.r_min, gains, rand_rank]
-            with enable_x64():
+            with jax.enable_x64(True):
                 if mesh is not None:
                     # placed INSIDE enable_x64: outside it device_put would
                     # canonicalize the float64 control state down to float32
@@ -390,7 +389,7 @@ def _prefilter_hybrid(state: ctl.ControlState, gains, rand_rank,
                              cfg.gamma)
     values = data_quality_value(state.reputations, I, cfg,
                                 omega=(w_rep[:, None], w_div[:, None]))
-    with enable_x64():
+    with jax.enable_x64(True):
         costs = np.asarray(ctl._cost_kernel(
             gains, state.r_min, cfg.bandwidth_hz, cfg.p_watt,
             cfg.n0_watt_hz, k=K)).astype(int)
@@ -482,3 +481,35 @@ def bytes_per_device(pop: PopulationState, n_devices: int) -> int:
     """Resident population-state bytes per device when the N axis is
     sharded over ``n_devices`` (policy_id and cfg scalars replicate)."""
     return pop.nbytes() // max(n_devices, 1) + pop.policy_id.nbytes
+
+
+# ---------------------------------------------------------------------- #
+# Synthetic population cell (benchmarks/bench_round.py, chip_smoke.py)
+# ---------------------------------------------------------------------- #
+def synthetic_population(n: int, k: int, n_runs: int):
+    """Seeded N-wide control state of ``n_runs`` stacked runs (policies
+    cycled over POLICY_IDS, K = ``k`` budget) and ``draw(t)`` -> per-run
+    (gains, rand_rank) of round t. Returns (state, omega, draw)."""
+    cfg = FeelConfig(n_ues=k, n_malicious=max(k // 10, 1), population=n)
+    rng = np.random.default_rng(0)
+    policies = [list(POLICY_IDS)[i % len(POLICY_IDS)] for i in range(n_runs)]
+    wm = WirelessModel(cfg, np.random.default_rng(1))
+    sizes = (rng.integers(1, 31, (n_runs, n)) * 50).astype(float)
+    cpu = rng.uniform(cfg.cpu_hz_min, cfg.cpu_hz_max, (n_runs, n))
+    state = ctl.ControlState(
+        policy_id=np.array([POLICY_IDS[p] for p in policies], np.int32),
+        sizes=sizes, divs=rng.uniform(0.0, 0.9, (n_runs, n)),
+        r_min=np.stack([wm.min_rate(wm.train_time(sizes[i], cpu[i]))
+                        for i in range(n_runs)]),
+        reputations=rng.uniform(0.0, 1.0, (n_runs, n)),
+        ages=np.ones((n_runs, n)), cfg=cfg)
+    omega = np.full(n_runs, cfg.omega_rep), np.full(n_runs, cfg.omega_div)
+
+    def draw(t):
+        g = np.stack([wm.rng.exponential(1.0, n) * wm.distances
+                      ** (-cfg.pathloss_exp) for _ in range(n_runs)])
+        rr = np.stack([np.argsort(np.random.default_rng((t, i))
+                                  .permutation(n)) for i in range(n_runs)])
+        return g, rr
+
+    return state, omega, draw

@@ -23,7 +23,7 @@ including the infeasible c = K+1 and blown-deadline t_train >= T edges).
 The module also exposes the pure-JAX twins (``rate_eq4``, ``cost_bisect``)
 used by the batched control plane (core/control.py): same formulas over
 arbitrary leading batch axes, jit/vmap-able, run in float64 (under
-``jax.experimental.enable_x64``) so they agree with the numpy oracle to
+``jax.enable_x64(True)``) so they agree with the numpy oracle to
 the last integer cost. The Eq. 9 right-hand side (min rates) is
 round-invariant, so the control plane precomputes it once per run with
 the numpy ``min_rate`` — there is deliberately no jnp twin for it.

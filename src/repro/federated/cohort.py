@@ -50,6 +50,8 @@ from functools import partial
 import jax
 import jax.numpy as jnp
 
+from repro.models.common import correct_counts
+
 
 @partial(jax.jit, static_argnames=("task", "epochs", "batch_size"))
 def cohort_train(task, params, data, mask, lr, epochs: int,
@@ -60,9 +62,10 @@ def cohort_train(task, params, data, mask, lr, epochs: int,
     define the per-client step; params — global model (broadcast to every
     client); data — per-sample array pytree with leaves (N, S, ...),
     mask (N, S) — the padded, stacked cohort.
-    Returns (stacked_params with leaves (N, ...), acc_local (N,)) where
+    Returns (stacked_params with leaves (N, ...), acc_local (N, 2)) where
     acc_local is each client's self-reported metric on its own (valid)
-    samples after local training (Alg. 1 line 11).
+    samples after local training (Alg. 1 line 11), as (correct, valid)
+    counts for ``models.common.count_accuracy``.
     """
     def one(di, mi):
         # fori_loop (not Python unrolling) keeps the traced epoch body
@@ -158,13 +161,13 @@ def cohort_eval(task, stacked_params, eval_inputs, y_units, masks):
     test pytree; y_units (U,) — unit-level labels (test labels for MNIST,
     next-token targets for the LM); masks (N, U) — per-UE evaluation unit
     masks (the server restricts Eq. 1's acc_test to the symbols a UE
-    claims to hold). Returns (N,) unit accuracies, 0.0 where a mask is
-    empty.
+    claims to hold). Returns (N, 2) (correct, valid) unit counts;
+    ``models.common.count_accuracy`` divides them on the host.
     """
     def one(p, m):
         correct = (task.predict_units(p, eval_inputs)
                    == y_units).astype(jnp.float32)
-        return jnp.sum(correct * m) / jnp.maximum(jnp.sum(m), 1.0)
+        return correct_counts(correct, m)
 
     return jax.vmap(one)(stacked_params, masks)
 
@@ -181,7 +184,7 @@ def cohort_eval_rows(task, stacked_params, eval_inputs, y_rows, masks):
     def one(p, yr, m):
         correct = (task.predict_units(p, eval_inputs)
                    == yr).astype(jnp.float32)
-        return jnp.sum(correct * m) / jnp.maximum(jnp.sum(m), 1.0)
+        return correct_counts(correct, m)
 
     return jax.vmap(one)(stacked_params, y_rows, masks)
 

@@ -12,13 +12,11 @@ aggregation.
 """
 from __future__ import annotations
 
-import functools
 from typing import Callable, Tuple
 
 import jax
 import jax.numpy as jnp
-from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-from jax.experimental.shard_map import shard_map
+from jax.sharding import Mesh, PartitionSpec as P
 
 
 def make_cohort_step(mesh: Mesh, loss_fn: Callable, lr: float,
@@ -62,10 +60,10 @@ def make_cohort_step(mesh: Mesh, loss_fn: Callable, lr: float,
         return out
 
     client_spec = P(client_axes)
-    fn = shard_map(cohort_body, mesh=mesh,
-                   in_specs=(P(), client_spec, client_spec, client_spec),
-                   out_specs=P(),
-                   check_rep=False)
+    fn = jax.shard_map(cohort_body, mesh=mesh,
+                       in_specs=(P(), client_spec, client_spec, client_spec),
+                       out_specs=P(),
+                       check_vma=False)
     return jax.jit(fn)
 
 

@@ -64,6 +64,7 @@ from repro.data.partition import (ClientData, pad_clients,
 from repro.federated import cohort
 from repro.federated.aggregation import fedavg, fedavg_stacked
 from repro.federated.task import FeelTask, as_task
+from repro.models.common import count_accuracy
 from repro.obs import trace
 
 
@@ -640,7 +641,7 @@ class FeelServer:
             parts.append((pos,
                           jax.tree.map(lambda l, m=pos.size: l[:m],
                                        stacked_b),
-                          np.asarray(acc_b, float)[:pos.size]))
+                          count_accuracy(acc_b)[:pos.size]))
             pad_slots += rows.size * bkt["level"]
         stacked, acc_local = self._merge_cohort(parts)
         self.pad_waste.append(
@@ -658,9 +659,9 @@ class FeelServer:
         with trace.span("eval") as esp:
             probe0 = (trace.jit_cache_size(cohort.cohort_eval)
                       if trace.enabled() else 0)
-            acc_test = np.asarray(
+            acc_test = count_accuracy(
                 cohort.cohort_eval(self.task, stacked_p, self._ex, self._ey,
-                                   self._eval_masks(sel, n_pad)), float)[:n]
+                                   self._eval_masks(sel, n_pad)))[:n]
             if trace.enabled():
                 esp.set(rows=int(n_pad),
                         compiled=trace.jit_cache_size(
@@ -689,9 +690,9 @@ class FeelServer:
             vm = self._val_eval_masks(sel, n_pad)
             both = cohort.merge_stacks(
                 [stacked_p, cohort.broadcast_params(self.params, n_pad)])
-            acc = np.asarray(
+            acc = count_accuracy(
                 cohort.cohort_eval(self.task, both, self._ex, self._ey,
-                                   jnp.concatenate([vm, vm])), float)
+                                   jnp.concatenate([vm, vm])))
             if trace.enabled():
                 sp.set(rows=int(2 * n_pad))
             return np.stack([acc[:n], acc[n_pad:n_pad + n]])

@@ -64,6 +64,7 @@ from repro.federated import cohort
 from repro.federated.async_engine import AsyncFeelEngine
 from repro.federated.server import FeelServer, build_cohort_data
 from repro.federated.task import FeelTask, as_task
+from repro.models.common import count_accuracy
 from repro.obs import trace
 
 
@@ -642,7 +643,7 @@ def _train_runs_stacked(runs: List[_SweepRun], t: int) -> None:
         g_off += n_pad
 
     big = cohort.merge_stacks(stacks)        # (g_off, ...) round stack
-    acc_all = np.asarray(jnp.concatenate(acc_parts), float)  # one sync
+    acc_all = count_accuracy(jnp.concatenate(acc_parts))  # one sync
     for i, run in enumerate(runs):
         order = np.concatenate([pos for pos, _ in row_map[i]])
         gidx = np.concatenate([g for _, g in row_map[i]])
@@ -831,14 +832,14 @@ def _eval_stacked(server, stacks, masks, counts, ys=None) -> List[np.ndarray]:
     stacked = cohort.pad_stacked(cohort.merge_stacks(stacks), n_pad)
     mask = cohort.pad_stacked(cohort.merge_stacks(masks), n_pad)
     if ys is None:
-        acc = np.asarray(
+        acc = count_accuracy(
             cohort.cohort_eval(server.task, stacked, server._ex,
-                               server._ey, mask), float)
+                               server._ey, mask))
     else:
         y_rows = cohort.pad_stacked(cohort.merge_stacks(ys), n_pad)
-        acc = np.asarray(
+        acc = count_accuracy(
             cohort.cohort_eval_rows(server.task, stacked, server._ex,
-                                    y_rows, mask), float)
+                                    y_rows, mask))
     out, off = [], 0
     for c in counts:
         out.append(acc[off:off + c])

@@ -66,9 +66,10 @@ from repro.data.partition import (GROUP_SIZE, MAX_GROUPS, MIN_GROUPS,
 from repro.data.synthetic_mnist import N_CLASSES, generate
 from repro.data.tokens import make_windows
 from repro.federated.client import ClientReport, local_train
-from repro.models.mlp import (mlp_accuracy, mlp_accuracy_masked, mlp_apply,
+from repro.models.common import count_accuracy
+from repro.models.mlp import (mlp_accuracy, mlp_apply, mlp_correct_counts,
                               mlp_init, mlp_sgd_epoch_masked)
-from repro.models.transformer import (lm_accuracy_masked, lm_forward,
+from repro.models.transformer import (lm_correct_counts, lm_forward,
                                       lm_init, lm_loss, lm_sgd_epoch,
                                       lm_sgd_epoch_masked)
 
@@ -154,7 +155,7 @@ class MnistTask(FeelTask):
                                     batch_size)
 
     def local_metric(self, params, d, m):
-        return mlp_accuracy_masked(params, d["x"], d["y"], m)
+        return mlp_correct_counts(params, d["x"], d["y"], m)
 
     def predict_units(self, params, ei):
         return jnp.argmax(mlp_apply(params, ei["x"]), -1)
@@ -287,7 +288,7 @@ class LmTask(FeelTask):
                                    batch_size)
 
     def local_metric(self, params, d, m):
-        return lm_accuracy_masked(self.model, params, d["tokens"], m)
+        return lm_correct_counts(self.model, params, d["tokens"], m)
 
     def predict_units(self, params, ei):
         logits, _, _, _ = lm_forward(self.model, params, ei["tokens"],
@@ -307,7 +308,8 @@ class LmTask(FeelTask):
             params = lm_sgd_epoch(self.model, params, tokens, lr,
                                   batch_size)
         m = jnp.ones(tokens.shape[0], jnp.float32)
-        acc = float(lm_accuracy_masked(self.model, params, tokens, m))
+        acc = float(count_accuracy(
+            lm_correct_counts(self.model, params, tokens, m)))
         return ClientReport(ue_id=client.ue_id, params=params,
                             acc_local=acc, n_samples=client.size)
 
@@ -339,14 +341,12 @@ class LmTask(FeelTask):
 
 
 def _f32_masked_acc(correct: np.ndarray, m: np.ndarray) -> float:
-    """Masked accuracy with ``cohort.cohort_eval``'s float32 arithmetic
-    (exact-integer f32 sums, f32 division) so the loop engine's host-side
-    Eq. 1 inputs are BIT-equal to the vectorized engine's device evals —
-    a float64 ``.mean()`` here would differ in the last mantissa bit and
-    fork the reputation streams."""
-    num = np.float32((correct & m).sum())
-    den = np.maximum(np.float32(m.sum()), np.float32(1.0))
-    return float(num / den)
+    """Masked accuracy from the same counts and the same host float32
+    quotient as the vectorized engine's device evals, so the loop
+    engine's Eq. 1 inputs are BIT-equal to them — a float64 ``.mean()``
+    here would differ in the last mantissa bit and fork the reputation
+    streams."""
+    return float(count_accuracy([(correct & m).sum(), m.sum()]))
 
 
 TASKS = {t.name: t for t in (MnistTask(), LmTask())}

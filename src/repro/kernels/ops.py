@@ -1,9 +1,10 @@
 """Jit'd public wrappers around the Pallas kernels.
 
-On this CPU-only container the kernels execute with ``interpret=True``
-(`REPRO_PALLAS_INTERPRET=1`, the default off-TPU); on TPU they compile to
-Mosaic. ``use_pallas()`` gates the model-level dispatch (models default to
-the XLA path; tests and benchmarks exercise the kernels explicitly).
+On the ``tpu`` backend the kernels compile to Mosaic; on the ``cpu``
+backend (the test suite) they run with ``interpret=True``; any other
+backend is an error. ``use_pallas()`` gates the model-level dispatch
+(models default to the XLA path; tests and benchmarks exercise the
+kernels explicitly).
 """
 from __future__ import annotations
 
@@ -23,10 +24,10 @@ from repro.kernels.weighted_aggregate import weighted_aggregate as _agg
 
 
 def _interpret() -> bool:
-    env = os.environ.get("REPRO_PALLAS_INTERPRET")
-    if env is not None:
-        return env not in ("0", "false")
-    return jax.default_backend() != "tpu"
+    backend = jax.default_backend()
+    if backend not in ("cpu", "tpu"):
+        raise RuntimeError(f"no Pallas path for backend {backend!r}")
+    return backend == "cpu"
 
 
 def use_pallas() -> bool:

@@ -7,6 +7,7 @@ dry-run launcher must set XLA_FLAGS before any jax initialisation.
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
 
 # TPU v5e hardware constants (per chip) — used by the roofline analysis.
 PEAK_FLOPS_BF16 = 197e12          # FLOP/s
@@ -17,10 +18,18 @@ ICI_BW = 50e9                     # bytes/s per link (~4 links usable/chip)
 ADAFACTOR_ARCHS = {"deepseek-v3-671b", "jamba-1.5-large-398b"}
 
 
+def _auto_mesh(shape, axes):
+    # the model and population code is written for GSPMD propagation
+    # (with_sharding_constraint, sharded placement); jax.make_mesh's
+    # default Explicit axes would demand an out_sharding at every
+    # ambiguous gather and contraction instead
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
+
+
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return _auto_mesh(shape, axes)
 
 
 def make_host_mesh(model_parallel: int = 1):
@@ -34,4 +43,4 @@ def make_host_mesh(model_parallel: int = 1):
     """
     n = len(jax.devices())
     mp = min(model_parallel, n)
-    return jax.make_mesh((n // mp, mp), ("data", "model"))
+    return _auto_mesh((n // mp, mp), ("data", "model"))

@@ -27,6 +27,7 @@ from typing import Dict, Optional
 
 from repro.configs.base import FeelConfig
 from repro.federated.simulation import run_experiment
+from repro.launch.compile_cache import use_compile_cache
 
 
 def simulate(policy: str = "dqs", task: Optional[str] = None,
@@ -89,6 +90,7 @@ def main(argv=None) -> int:
                          "the JSONL trace to PATH; inspect with "
                          "python -m repro.obs.report PATH")
     args = ap.parse_args(argv)
+    use_compile_cache()
 
     if args.trace:
         from repro.obs import trace

@@ -11,6 +11,25 @@ def dtype_of(cfg):
 
 
 # ---------------------------------------------------------------------- #
+# Accuracy as counts
+# ---------------------------------------------------------------------- #
+def correct_counts(correct, m):
+    """(2,) float32 (correct, valid) counts of a {0,1} score under mask
+    ``m`` — exact integers below 2^24. ``count_accuracy`` divides them."""
+    return jnp.stack([jnp.sum(correct * m), jnp.sum(m)])
+
+
+def count_accuracy(counts) -> np.ndarray:
+    """Accuracy from (..., 2) (correct, valid) counts: the float32
+    quotient taken on the host, 0.0 where nothing is valid. A device
+    divide is not correctly rounded on every backend (the TPU's is not),
+    so the engines' device evals and the loop oracle's host evals all
+    divide here and agree bit for bit on any backend."""
+    c = np.asarray(counts, np.float32)
+    return (c[..., 0] / np.maximum(c[..., 1], np.float32(1.0))).astype(float)
+
+
+# ---------------------------------------------------------------------- #
 # Initialisation
 # ---------------------------------------------------------------------- #
 def dense_init(key, shape, dtype, fan_in=None):

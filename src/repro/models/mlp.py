@@ -7,7 +7,7 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
-from repro.models.common import dense_init
+from repro.models.common import correct_counts, count_accuracy, dense_init
 
 
 def mlp_init(key, n_in: int = 28 * 28, n_hidden: int = 64, n_out: int = 10,
@@ -36,7 +36,10 @@ def mlp_loss(params, batch):
 
 
 def mlp_accuracy(params, x, y):
-    return jnp.mean((jnp.argmax(mlp_apply(params, x), -1) == y).astype(jnp.float32))
+    """Fraction of correct predictions, divided on the host like every
+    masked eval's counts (``models.common.count_accuracy``)."""
+    correct = (jnp.argmax(mlp_apply(params, x), -1) == y).astype(jnp.float32)
+    return count_accuracy(correct_counts(correct, jnp.ones_like(correct)))
 
 
 from functools import partial
@@ -81,10 +84,11 @@ def mlp_loss_masked(params, batch):
     return jnp.sum((logz - ll) * m) / jnp.maximum(jnp.sum(m), 1.0)
 
 
-def mlp_accuracy_masked(params, x, y, m):
-    """Accuracy over the valid samples only (0.0 when the mask is empty)."""
+def mlp_correct_counts(params, x, y, m):
+    """(correct, valid) counts over the valid samples
+    (``models.common.count_accuracy`` turns them into an accuracy)."""
     correct = (jnp.argmax(mlp_apply(params, x), -1) == y).astype(jnp.float32)
-    return jnp.sum(correct * m) / jnp.maximum(jnp.sum(m), 1.0)
+    return correct_counts(correct, m)
 
 
 @partial(jax.jit, static_argnums=(5,))

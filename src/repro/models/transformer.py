@@ -13,8 +13,8 @@ import jax
 import jax.numpy as jnp
 
 from repro.models import blocks as blk
-from repro.models.common import (cross_entropy, dtype_of, embed_init, ones,
-                                 rms_norm, dense_init)
+from repro.models.common import (correct_counts, cross_entropy, dtype_of,
+                                 embed_init, ones, rms_norm, dense_init)
 from repro.sharding.ctx import constrain
 
 
@@ -141,15 +141,16 @@ def lm_loss_masked(cfg, params, batch, *, remat=False):
     return loss + aux, {"ce": loss, "aux": aux}
 
 
-def lm_accuracy_masked(cfg, params, tokens, m):
-    """Masked next-token (greedy top-1) accuracy — the LM analogue of the
-    MLP's masked local accuracy (Alg. 1 line 11); 0.0 on an empty mask."""
+def lm_correct_counts(cfg, params, tokens, m):
+    """Masked next-token (greedy top-1) (correct, valid) counts — the LM
+    analogue of the MLP's masked local accuracy (Alg. 1 line 11), divided
+    by ``models.common.count_accuracy``."""
     logits, _, _, _ = lm_forward(cfg, params, tokens,
                                  window=cfg.sliding_window)
     correct = (jnp.argmax(logits[:, :-1], -1)
                == tokens[:, 1:]).astype(jnp.float32)
     w = _token_weights(tokens, m)
-    return jnp.sum(correct * w) / jnp.maximum(jnp.sum(w), 1.0)
+    return correct_counts(correct, w)
 
 
 @partial(jax.jit, static_argnums=(0, 4))

@@ -341,11 +341,11 @@ def test_dtype_f64_requires_x64_scope():
     """)
     assert [v.rule for v in lint_dtype_f64(bad)] == ["dtype-f64"]
     good = _src("""
+        import jax
         import jax.numpy as jnp
-        from jax.experimental import enable_x64
 
         def promote(x):
-            with enable_x64():
+            with jax.enable_x64(True):
                 return x.astype(jnp.float64)
     """)
     assert lint_dtype_f64(good) == []

@@ -17,6 +17,7 @@ from repro.federated import cohort
 from repro.federated.server import FeelServer
 from repro.federated.simulation import run_experiment
 from repro.federated.task import MnistTask
+from repro.models.common import count_accuracy
 from repro.models.mlp import (mlp_accuracy, mlp_init, mlp_sgd_epoch,
                               mlp_sgd_epoch_masked)
 
@@ -96,7 +97,7 @@ def test_cohort_eval_matches_subset_eval():
     masks = np.stack([np.isin(test.y, [0, 1, 2]),
                       np.isin(test.y, [5]),
                       np.ones_like(test.y, bool)]).astype(np.float32)
-    got = np.asarray(cohort.cohort_eval(
+    got = count_accuracy(cohort.cohort_eval(
         task, stacked, task.eval_inputs(test), jnp.asarray(test.y),
         jnp.asarray(masks)))
     for i, p in enumerate(params):

@@ -24,7 +24,6 @@ def test_flag_dispatch_matches_oracle(arch, monkeypatch):
     monkeypatch.setenv("REPRO_USE_PALLAS", "0")
     base, _, _, _ = tf.lm_forward(cfg, params, tok, window=cfg.sliding_window)
     monkeypatch.setenv("REPRO_USE_PALLAS", "1")
-    monkeypatch.setenv("REPRO_PALLAS_INTERPRET", "1")
     fused, _, _, _ = tf.lm_forward(cfg, params, tok, window=cfg.sliding_window)
     np.testing.assert_allclose(np.asarray(base), np.asarray(fused),
                                atol=2e-4, rtol=2e-4)

@@ -60,13 +60,14 @@ os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
 import dataclasses, functools, jax, jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P
 from repro.configs import TrainConfig, get, reduced
+from repro.launch.mesh import make_host_mesh
 from repro.launch.steps import make_train_step
 from repro.models import api
 from repro.sharding.specs import param_specs, opt_state_specs
 from repro.optim import make_optimizer
 
 cfg = dataclasses.replace(reduced(get("qwen2-moe-a2.7b")), vocab_size=1024)
-mesh = jax.make_mesh((2, 4), ("data", "model"))
+mesh = make_host_mesh(model_parallel=4)        # (2, 4) over 8 devices
 tcfg = TrainConfig(optimizer="adamw")
 params = jax.eval_shape(functools.partial(api.init, cfg), jax.random.PRNGKey(0))
 pspecs = param_specs(cfg, params, mesh)

@@ -1,9 +1,9 @@
 """Wireless model (paper Eq. 4-7, 9) properties, including the O(K log K)
 monotone-bisection cost against the exhaustive (K, K) scan oracle."""
+import jax
 import numpy as np
 import pytest
 from hypothesis_compat import given, settings, st
-from jax.experimental import enable_x64
 
 from repro.configs.base import FeelConfig
 from repro.core.wireless import WirelessModel, cost_bisect, dbm_to_watt
@@ -109,7 +109,7 @@ def test_cost_bisect_jnp_matches_numpy(seed, k):
     """The jnp twin (batched control plane) reproduces the numpy bisection
     exactly in float64, same edges included."""
     cfg, wm, gains, tt = _random_cost_instance(seed, k)
-    with enable_x64():
+    with jax.enable_x64(True):
         jc = np.asarray(cost_bisect(
             gains, np.asarray(wm.min_rate(tt)), k, cfg.bandwidth_hz,
             cfg.p_watt, cfg.n0_watt_hz))
@@ -121,7 +121,7 @@ def test_cost_bisect_jnp_batched_axes():
     control plane feeds it."""
     cfg, wm, gains, tt = _random_cost_instance(0, 23)
     r_min = np.asarray(wm.min_rate(tt))
-    with enable_x64():
+    with jax.enable_x64(True):
         single = np.asarray(cost_bisect(
             gains, r_min, 23, cfg.bandwidth_hz, cfg.p_watt,
             cfg.n0_watt_hz))
