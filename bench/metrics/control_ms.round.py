@@ -1,0 +1,19 @@
+"""Milliseconds per run-round in the control plane: Eq. 2/3 values,
+Eq. 9 costs, Alg. 2 selection and the Eq. 1 finalize.
+
+The self time (a span's duration less its direct children's) of the
+program's obs spans named in SPANS, summed over the traced window and
+divided by the run-rounds completed in it.
+"""
+import trace_reduce
+
+SPANS = ("schedule", "schedule.pack", "schedule.prefilter",
+         "schedule.finalize", "finalize")
+
+
+def read(run):
+    if not run.spans:
+        return None
+    own = trace_reduce.self_times(run.spans)
+    total = sum(own.get(n, 0.0) for n in SPANS)
+    return total * 1e3 / run.units if total > 0 else None
