@@ -1110,7 +1110,7 @@ def smoke():
         np.isfinite(c["final_acc"]) for c in async_cells), async_cells
     # observability plane (DESIGN.md §14): a traced engines cell — the
     # worker hands its trace back through REPRO_TRACE_FILE and the report
-    # must see schedule/train phase timings plus roofline context for both
+    # must see the schedule/train phase timings
     import tempfile
     from repro.obs import report as obs_report
     with tempfile.TemporaryDirectory() as td:
@@ -1121,8 +1121,6 @@ def smoke():
         rep = obs_report.summarize(tpath)
     for ph in ("round", "schedule", "train", "eval"):
         assert ph in rep["phases"], (ph, sorted(rep["phases"]))
-    for ph in ("schedule", "train"):
-        assert ph in rep["roofline"], (ph, sorted(rep["roofline"]))
     n_spans = int(sum(p["count"] for p in rep["phases"].values()))
     print(f"trace,{n_spans},{len(rep['phases'])},"
           f"{rep['phases']['round']['total_s']:.3f},"

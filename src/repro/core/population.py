@@ -282,14 +282,6 @@ def _prefilter_kernel(policy_id, rep, ages, divs, sizes, r_min, gains,
 # ---------------------------------------------------------------------- #
 # Entry point
 # ---------------------------------------------------------------------- #
-def _state_nbytes(state: ctl.ControlState) -> int:
-    """Resident bytes of the (R, N) control-plane state — the same
-    accounting as ``PopulationState.nbytes`` (telemetry gauge only)."""
-    return sum(np.asarray(a).nbytes
-               for a in (state.sizes, state.divs, state.r_min,
-                         state.reputations, state.ages))
-
-
 def prefilter_schedule_runs(state: ctl.ControlState, gains, rand_rank,
                             w_rep, w_div, m: Optional[int] = None,
                             kernel: Optional[str] = None, mesh=None):
@@ -304,7 +296,11 @@ def prefilter_schedule_runs(state: ctl.ControlState, gains, rand_rank,
 
     ``mesh`` (jax layout only) places the (R, N) operands with the
     population axis sharded over the mesh's data axes before the kernel
-    runs, so XLA partitions the O(N) stages across devices.
+    runs, so XLA partitions the O(N) stages across devices; without one
+    they go to the default device. The jax layout times its three
+    host<->chip stages as child spans of ``schedule.prefilter``
+    (``.put``, ``.kernel``, ``.fetch``) and counts the bytes that cross
+    (``population.h2d_bytes``, ``population.d2h_bytes``).
     """
     cfg = state.cfg
     K = cfg.n_ues
@@ -322,29 +318,37 @@ def prefilter_schedule_runs(state: ctl.ControlState, gains, rand_rank,
                                     kernel=kernel)
             if trace.enabled():
                 sp.set(m=N, runs=int(R), width=int(N), n_escalated=0)
-                trace.gauge_set("population.nbytes",
-                                float(_state_nbytes(state)))
             return (*out, {"m": N, "n_escalated": 0})
 
         kern = kernel or ctl.default_kernel()
         if kern == "jax":
-            ops = [state.reputations, state.ages, state.divs, state.sizes,
-                   state.r_min, gains, rand_rank]
+            ops = [np.asarray(a) for a in (
+                state.reputations, state.ages, state.divs, state.sizes,
+                state.r_min, gains, rand_rank)]
+            sh = (None if mesh is None else
+                  named(mesh, PartitionSpec(None, data_axes(mesh))))
             with jax.enable_x64(True):
-                if mesh is not None:
-                    # placed INSIDE enable_x64: outside it device_put would
-                    # canonicalize the float64 control state down to float32
-                    # and silently break oracle bit-parity
-                    sh = named(mesh, PartitionSpec(None, data_axes(mesh)))
-                    ops = [jax.device_put(np.asarray(a), sh) for a in ops]
-                x, alpha, costs, values, forced, cert = _prefilter_kernel(
-                    state.policy_id, *ops, w_rep, w_div,
-                    np.asarray(cfg.gamma, float), cfg.bandwidth_hz,
-                    cfg.p_watt, cfg.n0_watt_hz,
-                    k=K, n_sel=cfg.min_selected, m=m_eff)
-            x, alpha = np.array(x), np.array(alpha)
-            costs, values = np.array(costs).astype(int), np.array(values)
-            forced, cert = np.array(forced), np.asarray(cert)
+                # placed INSIDE enable_x64: outside it device_put would
+                # canonicalize the float64 control state down to float32
+                # and silently break oracle bit-parity
+                with trace.span("schedule.prefilter.put"):
+                    ops = trace.ready(jax.device_put(ops, sh))
+                with trace.span("schedule.prefilter.kernel"):
+                    outs = trace.ready(_prefilter_kernel(
+                        state.policy_id, *ops, w_rep, w_div,
+                        np.asarray(cfg.gamma, float), cfg.bandwidth_hz,
+                        cfg.p_watt, cfg.n0_watt_hz,
+                        k=K, n_sel=cfg.min_selected, m=m_eff))
+            with trace.span("schedule.prefilter.fetch"):
+                x, alpha, costs, values, forced, cert = outs
+                x, alpha = np.array(x), np.array(alpha)
+                costs, values = np.array(costs).astype(int), np.array(values)
+                forced, cert = np.array(forced), np.asarray(cert)
+            if trace.enabled():
+                trace.counter_inc("population.h2d_bytes",
+                                  sum(a.nbytes for a in ops))
+                trace.counter_inc("population.d2h_bytes",
+                                  sum(o.nbytes for o in outs))
         else:
             x, alpha, costs, values, forced, cert = _prefilter_hybrid(
                 state, gains, rand_rank, w_rep, w_div, m_eff)
@@ -367,8 +371,6 @@ def prefilter_schedule_runs(state: ctl.ControlState, gains, rand_rank,
             sp.set(m=m_eff, runs=int(R), width=int(N),
                    n_escalated=int(bad.size))
             trace.counter_inc("population.escalations", int(bad.size))
-            trace.gauge_set("population.nbytes",
-                            float(_state_nbytes(state)))
         return (x, alpha, costs, values, forced,
                 {"m": m_eff, "n_escalated": int(bad.size)})
 

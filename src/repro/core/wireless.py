@@ -184,8 +184,14 @@ def cost_bisect(gains, r_min, k: int, bandwidth_hz, p_watt, n0):
     extra iterations are no-ops for feasible UEs, and infeasible UEs are
     overridden by the up-front whole-band probe.
     """
+    gains = jnp.asarray(gains)
+
     def ok(c):
-        return rate_eq4(gains, c / k, bandwidth_hz, p_watt, n0) >= r_min
+        # the fraction in the gains' precision: int32 / int is float32
+        # even under enable_x64, which would round Eq. 4's denominator
+        # to float32 and flip a cost whose rate lies within ~1e-8 of r_min
+        a = c.astype(gains.dtype) / k
+        return rate_eq4(gains, a, bandwidth_hz, p_watt, n0) >= r_min
 
     feasible = ok(jnp.full(gains.shape, k, jnp.int32))
     n_iter = max(1, math.ceil(math.log2(max(k, 2)))) + 1

@@ -197,10 +197,9 @@ def unstack(stacked_params, i: int):
 # ---------------------------------------------------------------------- #
 # telemetry probe surface (DESIGN.md §14)
 # ---------------------------------------------------------------------- #
-# The four jitted entry points of the data plane. The tracer's first-call
-# probe (obs.trace.jit_cache_size before/after a call) splits compile
-# from execute on the train spans, and the sweep/serve drivers snapshot
-# the whole map as compile-cache gauges at end of run.
+# The four jitted entry points of the data plane. The sweep/serve drivers
+# snapshot their compile-cache sizes (obs.trace.jit_cache_size) as
+# compile-cache gauges at end of run.
 JITTED_ENTRY_POINTS = {
     "cohort_train": cohort_train,
     "cohort_train_multi": cohort_train_multi,

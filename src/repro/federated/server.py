@@ -357,7 +357,6 @@ class FeelServer:
         self.unavailable: Optional[np.ndarray] = None
         self.pad_waste: List[float] = []   # per-round padded/real sample ratio
         self.logs: List[RoundLog] = []
-        self._n_params: Optional[int] = None   # telemetry-only param count
 
     # ------------------------------------------------------------------ #
     def _omega(self, round_t: int) -> Tuple[float, float]:
@@ -626,16 +625,14 @@ class FeelServer:
         for bkt, pos, rows in self._cohort_parts(sel, t):
             data, ms = self._gather_bucket(bkt, rows)
             with trace.span("train.bucket") as bsp:
-                probe0 = (trace.jit_cache_size(cohort.cohort_train)
-                          if trace.enabled() else 0)
+                probe0 = trace.compiles()
                 stacked_b, acc_b = cohort.cohort_train(
                     self.task, self.params, data, ms, self.lr,
                     cfg.local_epochs, self.batch_size)
                 if trace.enabled():
                     bsp.set(level=int(bkt["level"]), rows=int(rows.size),
                             real=int(pos.size),
-                            compiled=trace.jit_cache_size(
-                                cohort.cohort_train) > probe0)
+                            compiled=trace.compiles() > probe0)
                     trace.observe("train.bucket_occupancy",
                                   pos.size / rows.size)
             parts.append((pos,
@@ -657,15 +654,13 @@ class FeelServer:
         n_pad = cohort.pad_count(n, self._N_BUCKET)
         stacked_p = cohort.pad_stacked(stacked, n_pad)
         with trace.span("eval") as esp:
-            probe0 = (trace.jit_cache_size(cohort.cohort_eval)
-                      if trace.enabled() else 0)
+            probe0 = trace.compiles()
             acc_test = count_accuracy(
                 cohort.cohort_eval(self.task, stacked_p, self._ex, self._ey,
                                    self._eval_masks(sel, n_pad)))[:n]
             if trace.enabled():
                 esp.set(rows=int(n_pad),
-                        compiled=trace.jit_cache_size(
-                            cohort.cohort_eval) > probe0)
+                        compiled=trace.compiles() > probe0)
         acc_val = self._eval_validation(stacked_p, sel)
         return (stacked_p, self._cohort_weights(sel, stacked_p),
                 acc_local, acc_test, acc_val)
@@ -718,8 +713,7 @@ class FeelServer:
                 out = self._schedule_round_host(t)
             if trace.enabled():
                 values, sched, sel, forced = out
-                sp.set(t=t, n_selected=int(sel.size), forced=bool(forced),
-                       **self._schedule_estimates())
+                sp.set(t=t, n_selected=int(sel.size), forced=bool(forced))
             return out
 
     def _schedule_round_host(self, t: int):
@@ -789,8 +783,7 @@ class FeelServer:
         ``acc_val`` is None unless the defense has a validation detector."""
         with trace.span("train") as sp:
             if trace.enabled():
-                sp.set(t=t, engine=self.engine, n=int(sel.size),
-                       **self._train_estimates(sel))
+                sp.set(t=t, engine=self.engine, n=int(sel.size))
             if self.engine == "vectorized":
                 return self._run_cohort_vectorized(sel, t)
             return self._run_cohort_loop(sel, t)
@@ -899,36 +892,6 @@ class FeelServer:
         accuracies through its batched eval and only needs this extra)."""
         loss = self.task.eval_loss(self.params, self._ex)
         return float("nan") if loss is None else float(loss)
-
-    # ------------------------------------------------------------------ #
-    # Telemetry-only analytic cost estimates (DESIGN.md §14). Host
-    # metadata arithmetic (sizes, shapes) — never touches device values
-    # or the RNG stream; consumed by repro.obs.report's roofline context.
-    # ------------------------------------------------------------------ #
-    def _param_count(self) -> int:
-        if self._n_params is None:
-            self._n_params = int(sum(l.size for l in
-                                     jax.tree.leaves(self.params)))
-        return self._n_params
-
-    def _schedule_estimates(self) -> Dict[str, float]:
-        """~flops/bytes of one control-plane round over N candidates:
-        Eq. 2/3 elementwise (~40 flops/candidate), the ~64-probe Eq. 9
-        bisection, the N log N pack sort; ~12 f64 passes over the (N,)
-        control arrays."""
-        n = float(self.cfg.n_population)
-        flops = n * (40.0 + 64.0 * 8.0) + 2.0 * n * max(np.log2(n), 1.0)
-        return {"est_flops": float(flops), "est_bytes": float(8.0 * n * 12.0)}
-
-    def _train_estimates(self, sel: np.ndarray) -> Dict[str, float]:
-        """~flops/bytes of the round's local training: 6*P per
-        sample-step (fwd 2P + bwd 4P) over every real scheduled sample x
-        epochs; ~3 f32 param-array passes per batch step."""
-        p = float(self._param_count())
-        steps = float(self.sizes[sel].sum()) * self.cfg.local_epochs
-        batches = steps / max(self.batch_size, 1)
-        return {"est_flops": 6.0 * p * steps,
-                "est_bytes": 12.0 * p * max(batches, 1.0)}
 
     def run_round(self, t: int) -> RoundLog:
         with trace.span("round") as sp:

@@ -677,10 +677,7 @@ def _sweep_round_stacked(runs: List[_SweepRun], t: int,
         with trace.span("schedule") as sp:
             _schedule_runs_stacked(runs, sweep_ctrl, t)
             if trace.enabled():
-                est = runs[0].server._schedule_estimates()
-                sp.set(t=t, runs=len(runs),
-                       est_flops=est["est_flops"] * len(runs),
-                       est_bytes=est["est_bytes"] * len(runs))
+                sp.set(t=t, runs=len(runs))
     else:
         for run in runs:
             run.plan = run.server._schedule_round(t)
@@ -690,11 +687,7 @@ def _sweep_round_stacked(runs: List[_SweepRun], t: int,
         with trace.span("train") as sp:
             _train_runs_stacked(group, t)
             if trace.enabled():
-                ests = [r.server._train_estimates(r.plan[2])
-                        for r in group]
-                sp.set(task=group[0].task.name, runs=len(group),
-                       est_flops=sum(e["est_flops"] for e in ests),
-                       est_bytes=sum(e["est_bytes"] for e in ests))
+                sp.set(task=group[0].task.name, runs=len(group))
 
     # -- phase C: evaluate uploads — one call per (task, seed) ---------- #
     with trace.span("eval"):

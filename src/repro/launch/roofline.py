@@ -88,25 +88,3 @@ def model_flops(cfg, tokens: int, train: bool) -> float:
     n = cfg.param_count(active_only=True) - cfg.vocab_size * cfg.d_model
     mult = 6.0 if train else 2.0
     return mult * n * tokens
-
-
-def intensity_context(flops: float, hbm_bytes: float,
-                      measured_s: float = 0.0) -> Dict:
-    """Arithmetic-intensity context for a traced phase (repro.obs.report).
-
-    From analytic flops/bytes estimates attached to a span, derive the
-    roofline position against the v5e constants: intensity (FLOPs/byte),
-    the ridge point (PEAK/HBM_BW), which side of the roof the phase sits
-    on, the time floor implied by the roof, and — when a measured
-    wall-time is supplied — the attained fraction of that floor."""
-    assert flops >= 0 and hbm_bytes > 0
-    ai = flops / hbm_bytes
-    ridge = PEAK_FLOPS_BF16 / HBM_BW
-    floor_s = max(flops / PEAK_FLOPS_BF16, hbm_bytes / HBM_BW)
-    out = {"flops": flops, "hbm_bytes": hbm_bytes, "intensity": ai,
-           "ridge": ridge,
-           "bound": "compute" if ai >= ridge else "memory",
-           "time_floor_s": floor_s}
-    if measured_s > 0:
-        out["attained_frac"] = floor_s / measured_s
-    return out

@@ -10,9 +10,9 @@ traced value, and the disabled tracer (``REPRO_TRACE=0``, the default)
 is a shared-singleton no-op.
 
 Sinks: in-memory ring, JSONL trace file keyed commit+env (like
-``BENCH_history.jsonl``), Chrome/Perfetto ``trace_event`` export, and
-``python -m repro.obs.report`` for per-phase p50/p95 + roofline
-context.
+``BENCH_history.jsonl``), native JAX profiler annotations (one per span,
+on the profiler's clock), and ``python -m repro.obs.report`` for
+per-phase p50/p95 and compile offenders.
 """
 from repro.obs import trace  # noqa: F401
 

@@ -5,9 +5,9 @@ traffic, no RNG draws — the zero-semantic-footprint contract of
 DESIGN.md §14):
 
 * **counter** — monotone accumulator (``prefilter escalations``,
-  ``compile cache misses``).
+  ``programs lowered``, ``host<->chip bytes``).
 * **gauge**   — last-written value plus its running max (``async heap
-  depth``, ``population nbytes``, ``compile-cache entries``).
+  depth``, ``compile-cache entries``).
 * **observation** — streaming summary of a value series
   (count/sum/min/max plus a bounded reservoir of the most recent
   values for percentile reporting): ``padding-waste ratio``, ``bucket

@@ -5,12 +5,9 @@ Reads a JSONL trace written by ``obs/trace.py`` and prints:
 * the commit+env meta line the trace is keyed by;
 * per-phase wall-time summary (count, total, p50, p95), sorted by
   total descending;
-* top compile offenders — spans whose jit first-call probe marked a
-  fresh compile-cache entry, sorted by duration;
-* roofline context for phases that attached analytic ``est_flops`` /
-  ``est_bytes`` attributes (train, schedule): arithmetic intensity
-  against the v5e ridge point via ``launch/roofline.py`` and, from the
-  measured wall time, the attained fraction of the roofline floor;
+* top compile offenders — spans during which JAX lowered a program
+  (the ``compiled`` attribute, from the ``jax.compiles`` counter),
+  sorted by duration;
 * the counter/gauge/observation snapshot.
 
 ``--json`` emits the same content as one JSON object for tooling.
@@ -22,7 +19,6 @@ import json
 import sys
 from typing import Dict, List
 
-from repro.launch.roofline import intensity_context
 from repro.obs.trace import load_jsonl, phase_summary
 
 
@@ -36,38 +32,13 @@ def compile_offenders(spans: List[Dict], top: int = 10) -> List[Dict]:
             for s in hits[:top]]
 
 
-def roofline_context(spans: List[Dict]) -> Dict[str, Dict]:
-    """Aggregate est_flops/est_bytes per phase and place each phase on
-    the roofline."""
-    agg: Dict[str, List[float]] = {}
-    for s in spans:
-        attrs = s.get("attrs") or {}
-        if "est_flops" in attrs and "est_bytes" in attrs:
-            f, b, d = agg.setdefault(s["name"], [0.0, 0.0, 0.0])
-            agg[s["name"]] = [f + attrs["est_flops"],
-                              b + attrs["est_bytes"], d + s["dur"]]
-    out: Dict[str, Dict] = {}
-    for name, (flops, nbytes, dur) in sorted(agg.items()):
-        if nbytes > 0:
-            out[name] = intensity_context(flops, nbytes, measured_s=dur)
-    return out
-
-
 def summarize(path: str, top: int = 10) -> Dict:
     """Everything the CLI prints, as one dict (used by bench smoke)."""
     meta, spans, metrics = load_jsonl(path)
     return {"meta": meta,
             "phases": phase_summary(spans),
             "compile_offenders": compile_offenders(spans, top=top),
-            "roofline": roofline_context(spans),
             "metrics": metrics}
-
-
-def _fmt_eng(x: float) -> str:
-    for unit, scale in (("G", 1e9), ("M", 1e6), ("K", 1e3)):
-        if abs(x) >= scale:
-            return f"{x / scale:.2f}{unit}"
-    return f"{x:.2f}"
 
 
 def render(rep: Dict, out=None) -> None:
@@ -88,15 +59,6 @@ def render(rep: Dict, out=None) -> None:
         for o in rep["compile_offenders"]:
             extra = "".join(f" {k}={v}" for k, v in o["attrs"].items())
             w(f"compile,{o['name']},{o['dur_s']:.6f}{extra}\n")
-    if rep["roofline"]:
-        w("# roofline context (analytic est_flops/est_bytes vs v5e roof)\n")
-        for name, r in rep["roofline"].items():
-            att = (f" attained={r['attained_frac']:.2e}"
-                   if "attained_frac" in r else "")
-            w(f"roofline,{name},{_fmt_eng(r['flops'])}F,"
-              f"{_fmt_eng(r['hbm_bytes'])}B,"
-              f"AI={r['intensity']:.2f},ridge={r['ridge']:.0f},"
-              f"{r['bound']}-bound,floor={r['time_floor_s']:.3e}s{att}\n")
     m = rep.get("metrics") or {}
     for kind in ("counters", "gauges", "observations"):
         for name, v in (m.get(kind) or {}).items():

@@ -1,8 +1,9 @@
 """Span tracer for the round pipeline (DESIGN.md §14).
 
 A ``Span`` is a named, possibly-nested phase of a round — schedule
-(prefilter/pack/finalize), train (per-bucket dispatch, compile vs
-execute via the jit first-call probe), attack-apply, defense
+(prefilter{.put,.kernel,.fetch}/pack/finalize), train (per-bucket
+dispatch, compile vs execute via the ``jax.compiles`` counter),
+attack-apply, defense
 (aggregate/detect), eval — recorded on the monotonic wall clock
 (``obs/clock.py``) and, when the async engine is driving, on the
 simulated event clock as well (``sim_t0``/``sim_t1``).
@@ -19,11 +20,17 @@ The hard contract is **zero semantic footprint**:
   hot path never builds kwargs dicts when tracing is off.
 
 Sinks: the in-memory ring (``tracer().spans``), a JSONL file keyed
-commit+env like ``BENCH_history.jsonl`` (``flush_jsonl``), and a
-Chrome/Perfetto ``trace_event`` export (``to_trace_event``).  Set
-``REPRO_TRACE=1`` to enable and ``REPRO_TRACE_FILE=/path.jsonl`` to
-flush the ring at interpreter exit — that is how benchmark worker
-subprocesses hand traces back to the driver.
+commit+env like ``BENCH_history.jsonl`` (``flush_jsonl``), and the JAX
+profiler: while tracing is enabled every span also opens a
+``jax.profiler.TraceAnnotation`` of its name, so a profiler session
+records the spans on its own clock, nested as they ran, beside the
+device programs they dispatched. ``ready(x)`` blocks on ``x`` only
+while tracing is enabled, so a span can close when its device work has
+ended. A ``jax.monitoring`` listener counts the programs JAX lowers
+(``jax.compiles``) while tracing is enabled. Set ``REPRO_TRACE=1`` to
+enable and ``REPRO_TRACE_FILE=/path.jsonl`` to flush the ring at
+interpreter exit — that is how benchmark worker subprocesses hand
+traces back to the driver.
 """
 from __future__ import annotations
 
@@ -45,7 +52,7 @@ class Span:
     """One timed phase. Use as a context manager; never reused."""
 
     __slots__ = ("name", "sid", "parent", "depth", "t0", "t1",
-                 "sim_t0", "sim_t1", "attrs", "_tracer")
+                 "sim_t0", "sim_t1", "attrs", "_tracer", "_annotation")
 
     def __init__(self, tracer: "Tracer", name: str, sid: int,
                  parent: int, depth: int) -> None:
@@ -57,6 +64,7 @@ class Span:
         self.sim_t0 = self.sim_t1 = None  # simulated clock (async mode)
         self.attrs: Optional[Dict[str, Any]] = None
         self._tracer = tracer
+        self._annotation = None
 
     def set(self, **attrs: Any) -> "Span":
         if self.attrs is None:
@@ -65,6 +73,9 @@ class Span:
         return self
 
     def __enter__(self) -> "Span":
+        from jax import profiler
+        self._annotation = profiler.TraceAnnotation(self.name)
+        self._annotation.__enter__()
         tr = self._tracer
         tr._stack.append(self)
         if tr.sim_clock is not None:
@@ -84,6 +95,8 @@ class Span:
         if len(ring) >= tr.ring_size:
             del ring[: tr.ring_size // 2]
         ring.append(self)
+        self._annotation.__exit__(*exc)
+        self._annotation = None
         return False
 
     @property
@@ -218,12 +231,59 @@ def jit_cache_size(fn) -> int:
         return -1
 
 
+def ready(x):
+    """``jax.block_until_ready(x)`` while tracing is enabled, so that the
+    span around the call closes when its device work has ended; ``x``
+    untouched otherwise. Changes no value."""
+    if not _TRACER.enabled:
+        return x
+    import jax
+    return jax.block_until_ready(x)
+
+
+COMPILES = "jax.compiles"
+
+
+def compiles() -> int:
+    """Programs JAX has lowered since the registry's last reset, as heard
+    while tracing is enabled (the ``jax.compiles`` counter)."""
+    c = _TRACER.metrics.counters.get(COMPILES)
+    return 0 if c is None else c.value
+
+
+_LOWERING_EVENT: Optional[str] = None   # JAX's jaxpr-to-MLIR event name
+
+
+def _hear_lowering(event: str, duration: float, **kw: Any) -> None:
+    if event == _LOWERING_EVENT:
+        _TRACER.metrics.counter(COMPILES).inc()
+
+
+_LISTENING = False
+
+
+def _listen(on: bool) -> None:
+    """Register (or remove) the lowering listener behind ``jax.compiles``."""
+    global _LISTENING, _LOWERING_EVENT
+    if on == _LISTENING:
+        return
+    from jax import monitoring
+    if on:
+        from jax._src import dispatch
+        _LOWERING_EVENT = dispatch.JAXPR_TO_MLIR_MODULE_EVENT
+        monitoring.register_event_duration_secs_listener(_hear_lowering)
+    else:
+        monitoring.unregister_event_duration_listener(_hear_lowering)
+    _LISTENING = on
+
+
 def configure(enabled: Optional[bool] = None, path: Optional[str] = None,
               ring_size: Optional[int] = None, reset: bool = True) -> Tracer:
     """Reconfigure the singleton (tests, drivers). Resets the ring by
     default so runs do not bleed spans into each other."""
     if enabled is not None:
         _TRACER.enabled = enabled
+        _listen(enabled)
     if path is not None:
         _TRACER.path = path or None
     if ring_size is not None:
@@ -296,29 +356,6 @@ def load_jsonl(path: str):
     return meta, spans, metrics
 
 
-def to_trace_event(spans: Optional[Sequence[Union[Span, Dict]]] = None
-                   ) -> Dict[str, Any]:
-    """Chrome/Perfetto ``trace_event`` JSON (complete 'X' events, µs).
-    Accepts live ``Span`` objects or span dicts from ``load_jsonl``."""
-    recs = [s.to_dict() if isinstance(s, Span) else s
-            for s in (_TRACER.spans if spans is None else spans)]
-    base = min((r["t0"] for r in recs), default=0.0)
-    evs = []
-    for r in recs:
-        ev: Dict[str, Any] = {"name": r["name"], "ph": "X",
-                              "ts": (r["t0"] - base) * 1e6,
-                              "dur": max(r["t1"] - r["t0"], 0.0) * 1e6,
-                              "pid": 0, "tid": 0}
-        args = dict(r.get("attrs") or {})
-        if r.get("sim_t0") is not None:
-            args["sim_t0"] = r["sim_t0"]
-            args["sim_t1"] = r["sim_t1"]
-        if args:
-            ev["args"] = args
-        evs.append(ev)
-    return {"traceEvents": evs, "displayTimeUnit": "ms"}
-
-
 def _pct(sorted_vals: List[float], q: float) -> float:
     if not sorted_vals:
         return 0.0
@@ -358,5 +395,7 @@ def _arm_atexit() -> None:
         _ATEXIT_ARMED = True
 
 
-if _TRACER.enabled and _TRACER.path:
-    _arm_atexit()
+if _TRACER.enabled:
+    _listen(True)
+    if _TRACER.path:
+        _arm_atexit()

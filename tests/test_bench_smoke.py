@@ -63,7 +63,7 @@ def test_bench_round_smoke():
         assert any(line.startswith(f"async,{mode},") for line in
                    r.stdout.splitlines()), mode
     # observability plane: the traced cell's summary row made it out
-    # (smoke itself asserts the report sees schedule/train phases plus
-    # roofline context for both — DESIGN.md §14)
+    # (smoke itself asserts the report sees the schedule/train phases —
+    # DESIGN.md §14)
     assert any(line.startswith("trace,") for line in
                r.stdout.splitlines())

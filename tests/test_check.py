@@ -77,9 +77,8 @@ def test_async_plane_revived_serve_launcher():
 
 
 def test_observability_plane_live_obs_and_roofline():
-    """PR 10 (DESIGN.md §14) built the obs package and wired
-    launch/roofline.py into a production consumer (repro.obs.report's
-    roofline context) — all of it must be LIVE in the dead-inheritance
+    """The obs package (DESIGN.md §14) and launch/roofline.py (the
+    dry-run's roofline terms) must be LIVE in the dead-inheritance
     inventory, or the telemetry plane silently lost its last caller."""
     inv = run_checks().inventory
     dead = {m["module"] for m in inv["dead"]}

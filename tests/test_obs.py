@@ -13,10 +13,11 @@ The contract under test, in order of importance:
 3. Span discipline: well-formed nesting (parent interval contains the
    child, depth is parent+1), and in async mode every span inside the
    event loop carries both clocks with sim_t0 <= sim_t1.
-4. Sinks round-trip: JSONL file -> (meta, spans, metrics), Chrome
-   ``trace_event`` export, per-phase summaries, and the
-   ``repro.obs.report`` summarizer (incl. roofline context for the
-   schedule/train phases via the revived ``launch/roofline.py``).
+4. Sinks round-trip: JSONL file -> (meta, spans, metrics), per-phase
+   summaries, the ``repro.obs.report`` summarizer, and the native
+   profiler annotations (one ``TraceAnnotation`` per span, opened and
+   closed in nesting order). The ``jax.compiles`` counter hears every
+   program JAX lowers while tracing is on.
 5. ``write_bench_json`` attaches the per-phase summary to the
    BENCH_history.jsonl line when tracing is on (satellite of §14).
 """
@@ -36,7 +37,6 @@ if ROOT not in sys.path:
 
 from repro.configs.base import FeelConfig
 from repro.federated.simulation import run_experiment
-from repro.launch.roofline import intensity_context
 from repro.obs import report as obs_report
 from repro.obs import trace
 from repro.obs.metrics import MetricRegistry
@@ -238,13 +238,6 @@ def test_jsonl_and_trace_event_round_trip(tmp_path):
     assert metrics["gauges"] == snap["gauges"]
     # phase summary computed from the file == from the live ring
     assert trace.phase_summary(recs) == trace.phase_summary(spans)
-    # Chrome trace_event export: one complete event per span, µs scale
-    ev = trace.to_trace_event(recs)
-    assert ev["displayTimeUnit"] == "ms"
-    assert len(ev["traceEvents"]) == len(recs)
-    for e in ev["traceEvents"]:
-        assert e["ph"] == "X" and e["ts"] >= 0.0 and e["dur"] >= 0.0
-    json.loads(json.dumps(ev))               # serializable as-is
 
 
 def test_report_summarize_and_render(tmp_path):
@@ -258,20 +251,20 @@ def test_report_summarize_and_render(tmp_path):
     for phase in ("round", "schedule", "train", "eval"):
         assert phase in rep["phases"], sorted(rep["phases"])
         assert rep["phases"][phase]["count"] >= CFG.rounds
-    # roofline context for the phases that attach analytic estimates
-    for phase in ("schedule", "train"):
-        r = rep["roofline"][phase]
-        assert r["intensity"] > 0 and r["bound"] in ("compute", "memory")
-        assert 0 < r["time_floor_s"] < 10.0
     # compile offenders: the cold jit cache means round 0 compiled
     assert any(o["name"] == "train.bucket"
                for o in rep["compile_offenders"])
+    # the jax.compiles counter hears more than the data plane's entry
+    # points: the control kernels and eager ops lowered too
+    marked = sum(1 for s in trace.load_jsonl(path)[1]
+                 if (s.get("attrs") or {}).get("compiled"))
+    assert rep["metrics"]["counters"][trace.COMPILES] > marked
     out = io.StringIO()
     obs_report.render(rep, out=out)
     text = out.getvalue()
     assert text.startswith("# trace commit=")
     assert "phase,count,total_s,p50_s,p95_s" in text
-    assert "roofline,train," in text and "roofline,schedule," in text
+    assert f"metric,counters,{trace.COMPILES}," in text
     # the CLI entry point agrees with the library path
     rc = obs_report.main([path, "--json"])
     assert rc == 0
@@ -292,14 +285,88 @@ def test_report_cli_module_runs(tmp_path):
     assert "phase,count,total_s,p50_s,p95_s" in r.stdout
 
 
-def test_roofline_intensity_context():
-    # 1 FLOP/byte is far below the v5e ridge -> memory bound
-    lo = intensity_context(1e9, 1e9, measured_s=1.0)
-    assert lo["bound"] == "memory" and lo["intensity"] == 1.0
-    assert 0 < lo["attained_frac"] <= 1.0
-    hi = intensity_context(1e15, 1e9)
-    assert hi["bound"] == "compute" and "attained_frac" not in hi
-    assert hi["time_floor_s"] > 0
+# ---------------------------------------------------------------------- #
+# 4b. native profiler annotations, ready(), the compile counter
+# ---------------------------------------------------------------------- #
+def _annotation_recorder(monkeypatch):
+    """Replace jax.profiler.TraceAnnotation by a recorder of (open|close,
+    name) events; returns the event log."""
+    import jax.profiler
+    log = []
+
+    class Recorder:
+        def __init__(self, name):
+            self.name = name
+
+        def __enter__(self):
+            log.append(("open", self.name))
+            return self
+
+        def __exit__(self, *exc):
+            log.append(("close", self.name))
+            return False
+
+    monkeypatch.setattr(jax.profiler, "TraceAnnotation", Recorder)
+    return log
+
+
+def test_spans_open_profiler_annotations_in_nesting_order(monkeypatch):
+    log = _annotation_recorder(monkeypatch)
+    trace.configure(enabled=True)
+    with trace.span("round"):
+        with trace.span("schedule") as sp:
+            sp.set(t=0)
+            with trace.span("schedule.pack"):
+                pass
+        with trace.span("train"):
+            pass
+    assert log == [("open", "round"), ("open", "schedule"),
+                   ("open", "schedule.pack"), ("close", "schedule.pack"),
+                   ("close", "schedule"), ("open", "train"),
+                   ("close", "train"), ("close", "round")]
+    # the ring keeps the spans and their attributes as before
+    spans = trace.tracer().spans
+    assert [s.name for s in spans] == ["schedule.pack", "schedule",
+                                       "train", "round"]
+    assert spans[1].attrs == {"t": 0}
+
+
+def test_disabled_path_opens_no_annotation_and_ready_is_identity(
+        monkeypatch):
+    import jax.numpy as jnp
+    log = _annotation_recorder(monkeypatch)
+    trace.configure(enabled=False)
+    with trace.span("round"):
+        with trace.span("schedule"):
+            pass
+    assert log == [] and trace.tracer().spans == []
+    x, arr = object(), jnp.arange(3)
+    assert trace.ready(x) is x and trace.ready(arr) is arr
+
+
+def test_ready_blocks_and_keeps_values_when_enabled():
+    import jax.numpy as jnp
+    trace.configure(enabled=True)
+    y = jnp.arange(4.0) * 2.0
+    out = trace.ready({"y": y, "n": [y + 1.0]})
+    assert np.array_equal(np.asarray(out["y"]), [0.0, 2.0, 4.0, 6.0])
+    assert np.array_equal(np.asarray(out["n"][0]), [1.0, 3.0, 5.0, 7.0])
+
+
+def test_compile_counter_hears_a_control_kernel_and_stops_when_off():
+    from repro.core import control as ctl
+    trace.configure(enabled=True)
+    assert trace._LISTENING
+    c0 = trace.compiles()
+    # a shape and budget no other test uses: the first call lowers
+    ctl._pack_kernel(np.ones((3, 37), np.int32), k=7)
+    assert trace.compiles() == c0 + 1
+    ctl._pack_kernel(np.ones((3, 37), np.int32), k=7)   # cached
+    assert trace.compiles() == c0 + 1
+    trace.configure(enabled=False, reset=False)
+    assert not trace._LISTENING
+    ctl._pack_kernel(np.ones((3, 38), np.int32), k=7)
+    assert trace.compiles() == c0 + 1
 
 
 # ---------------------------------------------------------------------- #

@@ -268,3 +268,74 @@ def test_prefilter_sharded_mesh_parity():
                   root, os.environ.get("PYTHONPATH", "")])})
     assert r.returncode == 0, r.stderr[-2000:]
     assert "MESH-PARITY-OK" in r.stdout
+
+
+# ---------------------------------------------------------------------- #
+# Telemetry: the prefilter's host<->chip split (obs spans, byte counters)
+# ---------------------------------------------------------------------- #
+PREFILTER_CHILDREN = ["schedule.prefilter.put", "schedule.prefilter.kernel",
+                      "schedule.prefilter.fetch"]
+
+
+def _prefilter_jax(traced: bool, seed: int = 3, k: int = 8, n: int = 96):
+    """One jax-layout prefilter call with tracing on or off: (instance,
+    outputs, spans, counters)."""
+    from repro.obs import trace
+    inst = _instance(seed, k, n, r=5)
+    _, state, gains, rand_rank, omega = inst
+    trace.configure(enabled=traced)
+    try:
+        out = pop.prefilter_schedule_runs(state, gains, rand_rank, *omega,
+                                          m=2 * k, kernel="jax")
+        spans = list(trace.tracer().spans)
+        counters = dict(trace.tracer().metrics.snapshot()["counters"])
+    finally:
+        trace.configure(enabled=False)
+    return inst, out, spans, counters
+
+
+def test_prefilter_child_spans_nest_under_prefilter():
+    _, _, spans, _ = _prefilter_jax(True)
+    (parent,) = [s for s in spans if s.name == "schedule.prefilter"]
+    kids = sorted((s for s in spans if s.parent == parent.sid
+                   and s.name in PREFILTER_CHILDREN), key=lambda s: s.t0)
+    assert [s.name for s in kids] == PREFILTER_CHILDREN
+    for s in kids:
+        assert s.depth == parent.depth + 1
+        assert parent.t0 <= s.t0 <= s.t1 <= parent.t1
+    assert all(a.t1 <= b.t0 for a, b in zip(kids, kids[1:]))
+
+
+def test_prefilter_child_durations_fit_in_parent():
+    _, _, spans, _ = _prefilter_jax(True)
+    (parent,) = [s for s in spans if s.name == "schedule.prefilter"]
+    kids = [s for s in spans if s.parent == parent.sid
+            and s.name in PREFILTER_CHILDREN]
+    assert len(kids) == 3
+    assert sum(s.dur for s in kids) <= parent.dur
+
+
+def test_prefilter_byte_counters_equal_operand_and_output_nbytes():
+    inst, out, _, counters = _prefilter_jax(True)
+    _, state, gains, rand_rank, _ = inst
+    operands = (state.reputations, state.ages, state.divs, state.sizes,
+                state.r_min, gains, rand_rank)
+    assert counters["population.h2d_bytes"] == sum(
+        np.asarray(a).nbytes for a in operands)
+    # fetched as the kernel returns them: x, alpha, costs (int32), values,
+    # forced, cert
+    x, alpha, _, values, forced, _ = out
+    r, n = x.shape
+    assert counters["population.d2h_bytes"] == (
+        x.nbytes + alpha.nbytes + r * n * 4 + values.nbytes
+        + forced.nbytes + r)
+
+
+def test_prefilter_schedule_bit_identical_with_tracing_on_and_off():
+    _, off, spans_off, counters_off = _prefilter_jax(False)
+    _, on, spans_on, _ = _prefilter_jax(True)
+    assert spans_off == [] and counters_off == {} and spans_on
+    for a, b in zip(off[:5], on[:5]):
+        assert a.dtype == b.dtype
+        np.testing.assert_array_equal(a, b)
+    assert off[5] == on[5]

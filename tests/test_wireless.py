@@ -116,6 +116,27 @@ def test_cost_bisect_jnp_matches_numpy(seed, k):
     np.testing.assert_array_equal(jc, wm.cost(gains, tt))
 
 
+def test_cost_bisect_fraction_is_float64_at_a_near_boundary_rate():
+    """A UE whose float64 rate at c = 5 of K = 64 clears r_min by only
+    5e-9 (relative): the jnp twin must still give 5, as the float64
+    reference does. With the fraction c / K left float32 (int32 / int),
+    Eq. 4's denominator rounds to float32 and the cost reads 6."""
+    k, bw = 64, 1e6
+    p, n0 = dbm_to_watt(-23.0), dbm_to_watt(-174.0)
+    g = np.array([2.9260976020125843e-09])
+    r_min = np.array([436681.20528104715])
+
+    def rate(c):
+        a = c / k
+        return a * bw * np.log2(1.0 + g * p / (a * bw * n0))
+
+    assert rate(4)[0] < r_min[0] <= rate(5)[0]
+    assert rate(5)[0] / r_min[0] - 1.0 < 1e-8
+    with jax.enable_x64(True):
+        got = np.asarray(cost_bisect(g, r_min, k, bw, p, n0))
+    assert got.tolist() == [5]
+
+
 def test_cost_bisect_jnp_batched_axes():
     """cost_bisect accepts leading batch (run) axes — the (R, K) layout the
     control plane feeds it."""
