@@ -45,7 +45,11 @@ measure-zero comparison boundary (never observed; pinned exact on random
 instances). The jax layout matches the integer outputs (selection, costs,
 forced) bit-for-bit and the float outputs to ~1 ulp — XLA contracts
 ``a*b + c`` into FMAs and strength-reduces the divide-by-constant in
-alpha, so its last bit can differ from numpy's.
+alpha, so its last bit can differ from numpy's. That holds for
+``schedule_runs``; the population prefilter's jax layout
+(``core.population``) builds alpha on the host with the hybrid path's
+float64 division, so its alpha is exact except in rows it escalates
+here.
 
 The per-run path survives as ``FeelServer(..., control="host")`` — the
 bit-parity oracle, mirroring the ``engine="loop"`` pattern of the data

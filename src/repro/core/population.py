@@ -208,14 +208,13 @@ def _topm_prefix(keys: np.ndarray, m: int) -> np.ndarray:
 # ---------------------------------------------------------------------- #
 # "jax" layout: prefilter as ONE jitted vmapped (shardable) kernel
 # ---------------------------------------------------------------------- #
-@functools.partial(jax.jit, static_argnames=("k", "n_sel", "m"))
-def _prefilter_kernel(policy_id, rep, ages, divs, sizes, r_min, gains,
-                      rand_rank, w_rep, w_div, gamma, bandwidth_hz,
-                      p_watt, n0, *, k: int, n_sel: int, m: int):
-    """One prefiltered round of every run: (R, N) in, (x, alpha, costs,
-    values, forced, cert) out. The O(N) work (Eq. 2/3/9, top_k, the
-    global fallback reductions) is population-parallel and shards over
-    the mesh data axes; only the (R, M) sort + budget scan is serial."""
+def _prefilter_rows(policy_id, rep, ages, divs, sizes, r_min, gains,
+                    rand_rank, w_rep, w_div, gamma, bandwidth_hz,
+                    p_watt, n0, *, k: int, n_sel: int, m: int):
+    """``_prefilter_kernel``'s (x, costs, values, forced, cert) with
+    ``values`` one (R, N) array. Alpha is not computed on the chip:
+    ``_alpha_from_schedule`` derives it on the host from x, costs and
+    forced."""
 
     def one(pid, rep, ages, divs, sizes, r_min, gains, rand_rank,
             w_rep, w_div):
@@ -241,7 +240,6 @@ def _prefilter_kernel(policy_id, rep, ages, divs, sizes, r_min, gains,
         c_kept = jnp.take(costs, kept)
         take = pack_scan(c_kept, k)
         x = jnp.zeros(costs.shape, bool).at[kept].set(take)
-        alpha = jnp.where(x, costs_f / k, 0.0)
 
         # preservation certificate: remaining budget cannot admit any
         # dropped candidate (see module docstring)
@@ -257,26 +255,62 @@ def _prefilter_kernel(policy_id, rep, ages, divs, sizes, r_min, gains,
                   & (masked[k_best] > (values * x).sum()))
         onehot_best = jnp.zeros_like(x).at[k_best].set(True)
         x = jnp.where(use_fb, onehot_best, x)
-        alpha = jnp.where(use_fb,
-                          jnp.where(onehot_best, costs_f / k, 0.0), alpha)
 
         # top_value override: top-n_sel by value (ties to lower index ==
         # the exact path's stable rank)
         _, topn = jax.lax.top_k(values, n_sel)
         x4 = jnp.zeros_like(x).at[topn].set(True)
         x = jnp.where(pid == 4, x4, x)
-        alpha = jnp.where(pid == 4,
-                          jnp.where(x4, 1.0 / max(n_sel, 1), 0.0), alpha)
 
         # degenerate round: force the single highest-value UE
         forced = ~x.any()
         onehot_f = jnp.zeros_like(x).at[jnp.argmax(values)].set(True)
         x = jnp.where(forced, onehot_f, x)
-        alpha = jnp.where(forced, jnp.where(onehot_f, 1.0, 0.0), alpha)
-        return x, alpha, costs, values, forced, cert
+        return x, costs, values, forced, cert
 
     return jax.vmap(one)(policy_id, rep, ages, divs, sizes, r_min, gains,
                          rand_rank, w_rep, w_div)
+
+
+@functools.partial(jax.jit, static_argnames=("k", "n_sel", "m"))
+def _prefilter_kernel(policy_id, rep, ages, divs, sizes, r_min, gains,
+                      rand_rank, w_rep, w_div, gamma, bandwidth_hz,
+                      p_watt, n0, *, k: int, n_sel: int, m: int):
+    """One prefiltered round of every run: (R, N) in, (x, costs, values,
+    forced, cert) out, ``values`` as a tuple of R per-run (N,) buffers.
+    The O(N) work (Eq. 2/3/9, top_k, the global fallback reductions) is
+    population-parallel and shards over the mesh data axes; only the
+    (R, M) sort + budget scan is serial.
+
+    A TPU holds float64 as a pair of float32 words; the host converts
+    each element to IEEE binary64 as it copies, which costs far more
+    than the copy itself. R separate buffers are R transfers the
+    runtime converts side by side, where one (R, N) array converts as
+    one: on a TPU v5e host, (5, 10^6) float64 reads in 68-86 ms as five
+    buffers against 187-196 ms as one array. The TPU compiler has no
+    bitcast of float64, so the IEEE bits cannot be formed on the chip
+    instead."""
+    x, costs, values, forced, cert = _prefilter_rows(
+        policy_id, rep, ages, divs, sizes, r_min, gains, rand_rank, w_rep,
+        w_div, gamma, bandwidth_hz, p_watt, n0, k=k, n_sel=n_sel, m=m)
+    return x, costs, tuple(values), forced, cert
+
+
+def _alpha_from_schedule(policy_id, x, costs, forced, k: int, n_sel: int):
+    """The kernel's bandwidth fractions, rebuilt on the host from the
+    fetched schedule by its precedence: 1.0 at a forced row's UE,
+    ``1 / max(n_sel, 1)`` at a ``top_value`` row's, ``costs / k`` at
+    every other selected UE (the dqs fallback's included) — the same
+    float64 division as ``control._schedule_hybrid``. O(R*K) writes,
+    by flat index (``np.nonzero`` of a 2-D mask is ~10x slower)."""
+    idx = np.flatnonzero(x)
+    rows = idx // x.shape[1]
+    a = np.take(costs, idx).astype(float) / k
+    a = np.where(policy_id[rows] == POLICY_IDS["top_value"],
+                 1.0 / max(n_sel, 1), a)
+    alpha = np.zeros(x.shape)
+    np.put(alpha, idx, np.where(forced[rows], 1.0, a))
+    return alpha
 
 
 # ---------------------------------------------------------------------- #
@@ -300,7 +334,11 @@ def prefilter_schedule_runs(state: ctl.ControlState, gains, rand_rank,
     they go to the default device. The jax layout times its three
     host<->chip stages as child spans of ``schedule.prefilter``
     (``.put``, ``.kernel``, ``.fetch``) and counts the bytes that cross
-    (``population.h2d_bytes``, ``population.d2h_bytes``).
+    (``population.h2d_bytes``, ``population.d2h_bytes``). Its fetch is
+    one ``jax.device_get`` of x, costs (int32), values (R per-run float64
+    buffers, stacked on the host), forced and the certificate; alpha
+    does not cross but is rebuilt on the host (``_alpha_from_schedule``)
+    before escalated rows overwrite theirs.
     """
     cfg = state.cfg
     K = cfg.n_ues
@@ -340,15 +378,18 @@ def prefilter_schedule_runs(state: ctl.ControlState, gains, rand_rank,
                         cfg.p_watt, cfg.n0_watt_hz,
                         k=K, n_sel=cfg.min_selected, m=m_eff))
             with trace.span("schedule.prefilter.fetch"):
-                x, alpha, costs, values, forced, cert = outs
-                x, alpha = np.array(x), np.array(alpha)
-                costs, values = np.array(costs).astype(int), np.array(values)
-                forced, cert = np.array(forced), np.asarray(cert)
+                # one device_get starts every copy before it reads any
+                x, costs, values, forced, cert = jax.device_get(outs)
+                x, forced = np.array(x), np.array(forced)
+                costs, values = costs.astype(int), np.stack(values)
+            alpha = _alpha_from_schedule(state.policy_id, x, costs, forced,
+                                         K, cfg.min_selected)
             if trace.enabled():
                 trace.counter_inc("population.h2d_bytes",
                                   sum(a.nbytes for a in ops))
                 trace.counter_inc("population.d2h_bytes",
-                                  sum(o.nbytes for o in outs))
+                                  sum(o.nbytes for o in
+                                      jax.tree.leaves(outs)))
         else:
             x, alpha, costs, values, forced, cert = _prefilter_hybrid(
                 state, gains, rand_rank, w_rep, w_div, m_eff)

@@ -82,18 +82,143 @@ def test_prefilter_matches_exact_all_policies(seed, k, n_factor):
 @settings(max_examples=6, deadline=None)
 def test_prefilter_jax_matches_exact(seed, k):
     """The jax prefilter layout (lax.top_k cut, shardable) picks the
-    same UEs/costs/forced as the exact path; alpha to ~1 ulp."""
+    same UEs/costs/forced as the exact path. Its alpha is rebuilt on the
+    host by the hybrid path's own float64 expressions, so it is equal
+    where no row escalates; escalated rows take ``schedule_runs``' jax
+    layout, whose alpha is ~1 ulp off."""
     n = 8 * k
     cfg, state, gains, rand_rank, omega = _instance(seed, k, n)
     exact = ctl.schedule_runs(state, gains, rand_rank, *omega,
                               kernel="hybrid")
     for m in (cfg.min_selected + 1, 2 * k):
-        x, alpha, costs, values, forced, _ = pop.prefilter_schedule_runs(
-            state, gains, rand_rank, *omega, m=m, kernel="jax")
+        x, alpha, costs, values, forced, info = \
+            pop.prefilter_schedule_runs(state, gains, rand_rank, *omega,
+                                        m=m, kernel="jax")
         np.testing.assert_array_equal(x, exact[0], err_msg=f"m={m}")
         np.testing.assert_array_equal(costs, exact[2], err_msg=f"m={m}")
         np.testing.assert_array_equal(forced, exact[4], err_msg=f"m={m}")
         np.testing.assert_allclose(alpha, exact[1], rtol=1e-14, atol=0)
+        if info["n_escalated"] == 0:
+            np.testing.assert_array_equal(alpha, exact[1], err_msg=f"m={m}")
+
+
+def _branch_instance():
+    """An R = 5 instance whose rows reach every alpha branch of the jax
+    kernel: row 0 (dqs) takes the modified-greedy fallback (two feasible
+    UEs costing 2 and 7 of K = 8, the dearer one worth more), row 3
+    (max_count) is forced (no UE feasible), row 4 is top_value; rows 1
+    and 2 pack normally."""
+    cfg, state, gains, rand_rank, omega = _instance(0, 8, 64, r=5)
+    gains[0] *= 10
+    costs = ctl.schedule_runs(state, gains, rand_rank, *omega,
+                              kernel="hybrid")[2][0]
+    a, b = np.flatnonzero(costs == 2)[0], np.flatnonzero(costs == 7)[0]
+    others = np.ones(64, bool)
+    others[[a, b]] = False
+    gains[0, others] *= 1e-6
+    state.reputations[0, b] = 1.0
+    gains[3] *= 1e-6
+    return (cfg, state, gains, rand_rank, omega), b
+
+
+def _float64_fetch_prefilter(state, gains, rand_rank, omega, m):
+    """The jax layout as it read its kernel before alpha left it:
+    ``values`` fetched as one (R, N) float64 array, alpha by the
+    kernel's own jnp expression, failing certificates escalated."""
+    import jax
+    import jax.numpy as jnp
+    cfg = state.cfg
+    k, n_sel = cfg.n_ues, cfg.min_selected
+    with jax.enable_x64(True):
+        rows = jax.jit(pop._prefilter_rows,
+                       static_argnames=("k", "n_sel", "m"))
+        x, costs, values, forced, cert = rows(
+            state.policy_id, state.reputations, state.ages, state.divs,
+            state.sizes, state.r_min, gains, rand_rank, *omega,
+            np.asarray(cfg.gamma, float), cfg.bandwidth_hz, cfg.p_watt,
+            cfg.n0_watt_hz, k=k, n_sel=n_sel, m=m)
+        pid = jnp.asarray(state.policy_id)[:, None]
+        alpha = jnp.where(x, costs.astype(values.dtype) / k, 0.0)
+        alpha = jnp.where(pid == 4, jnp.where(x, 1.0 / max(n_sel, 1), 0.0),
+                          alpha)
+        alpha = jnp.where(forced[:, None], jnp.where(x, 1.0, 0.0), alpha)
+    x, alpha, values, forced = (np.array(a) for a in (x, alpha, values,
+                                                      forced))
+    costs = np.array(costs).astype(int)
+    bad = np.flatnonzero(~np.asarray(cert))
+    if bad.size:
+        sub = ctl.ControlState(
+            policy_id=state.policy_id[bad], sizes=state.sizes[bad],
+            divs=state.divs[bad], r_min=state.r_min[bad],
+            reputations=state.reputations[bad], ages=state.ages[bad],
+            cfg=cfg)
+        xs, als, cs, vs, fs = ctl.schedule_runs(
+            sub, gains[bad], rand_rank[bad], omega[0][bad], omega[1][bad],
+            kernel="jax")
+        x[bad], alpha[bad], costs[bad], values[bad], forced[bad] = \
+            xs, als, cs, vs, fs
+    return x, alpha, costs, values, forced, bad.size
+
+
+@pytest.mark.parametrize("case", ["branches", "escalated"])
+def test_prefilter_jax_bit_identical_to_float64_fetch(case):
+    """The jax layout's x, costs, values and forced are bit-identical to
+    the float64 fetch it replaced, and its host-built alpha equals the
+    kernel's old expression, on rows that reach each alpha branch:
+    forced, top_value, the dqs fallback and an escalated row."""
+    if case == "branches":
+        inst, b = _branch_instance()
+        m = 16
+    else:
+        inst = _instance(1, 8, 64)
+        m = inst[0].min_selected
+    cfg, state, gains, rand_rank, omega = inst
+    *new, info = pop.prefilter_schedule_runs(state, gains, rand_rank,
+                                             *omega, m=m, kernel="jax")
+    *old, n_bad = _float64_fetch_prefilter(state, gains, rand_rank, omega,
+                                           m)
+    x, alpha, costs, values, forced = new
+    if case == "branches":
+        assert forced[3] and not forced[[0, 1, 2, 4]].any()
+        assert state.policy_id[4] == 4 and x[4].sum() == cfg.min_selected
+        assert np.flatnonzero(x[0]).tolist() == [b] and costs[0, b] == 7
+        assert alpha[0, b] == 7 / 8 and alpha[3].sum() == 1.0
+    else:
+        assert info["n_escalated"] == n_bad > 0
+    for name, a, o in zip(("x", "costs", "values", "forced"),
+                          (x, costs, values, forced),
+                          (old[0], old[2], old[3], old[4])):
+        assert a.dtype == o.dtype, name
+        np.testing.assert_array_equal(a.view(np.uint8), o.view(np.uint8),
+                                      err_msg=name)
+    np.testing.assert_array_equal(alpha, old[1])
+
+
+@pytest.mark.parametrize("mesh", [False, True])
+def test_prefilter_values_cross_bit_exact(mesh):
+    """``values`` crosses as R per-run float64 buffers and reads back as
+    the one (R, N) float64 fetch did, bit for bit, without and with a
+    1-device mesh. With w_rep = 1 and w_div = -0.0, Eq. 3 is the
+    identity on the reputations, so 0.0, -0.0, 1.0 and random float64
+    planted there come back unchanged; subnormals come back as the
+    kernel's arithmetic leaves them (XLA's CPU flushes them to zero)."""
+    cfg, state, gains, rand_rank, _ = _instance(4, 8, 64, r=5)
+    omega = (np.ones(5), np.full(5, -0.0))
+    rng = np.random.default_rng(4)
+    planted = np.r_[0.0, -0.0, 5e-324, np.finfo(float).tiny / 3, 1.0,
+                    rng.standard_normal(20)
+                    * 10.0 ** rng.integers(-300, 300, 20)]
+    state.reputations[:, :planted.size] = planted
+    values = pop.prefilter_schedule_runs(
+        state, gains, rand_rank, *omega, m=16, kernel="jax",
+        mesh=pop.population_mesh() if mesh else None)[3]
+    old = _float64_fetch_prefilter(state, gains, rand_rank, omega, 16)[3]
+    np.testing.assert_array_equal(values.view(np.uint64),
+                                  old.view(np.uint64))
+    rep = state.reputations
+    normal = (rep == 0) | (np.abs(rep) >= np.finfo(float).tiny)
+    np.testing.assert_array_equal(values.view(np.uint64)[normal],
+                                  rep.view(np.uint64)[normal])
 
 
 def test_prefilter_escalation_is_exercised():
@@ -244,11 +369,14 @@ assert mesh.devices.size == 2
 cfg, state, gains, rand_rank, omega = _instance(11, 8, 160)
 exact = ctl.schedule_runs(state, gains, rand_rank, *omega,
                           kernel="hybrid")
-x, _, costs, _, forced, info = pop.prefilter_schedule_runs(
+x, _, costs, values, forced, info = pop.prefilter_schedule_runs(
     state, gains, rand_rank, *omega, m=32, kernel="jax", mesh=mesh)
 np.testing.assert_array_equal(x, exact[0])
 np.testing.assert_array_equal(costs, exact[2])
 np.testing.assert_array_equal(forced, exact[4])
+one = pop.prefilter_schedule_runs(state, gains, rand_rank, *omega, m=32,
+                                  kernel="jax")
+np.testing.assert_array_equal(values.view(np.uint64), one[3].view(np.uint64))
 print("MESH-PARITY-OK")
 """
 
@@ -256,7 +384,8 @@ print("MESH-PARITY-OK")
 def test_prefilter_sharded_mesh_parity():
     """Forced 2-device host mesh (subprocess: conftest pins no XLA_FLAGS
     in-process): the GSPMD-sharded prefilter kernel still selects the
-    exact cohort."""
+    exact cohort, and its per-run values buffers, sharded by UE, read
+    back bit for bit as on one device."""
     root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     r = subprocess.run(
         [sys.executable, "-c", _MESH_PARITY], capture_output=True,
@@ -322,13 +451,12 @@ def test_prefilter_byte_counters_equal_operand_and_output_nbytes():
                 state.r_min, gains, rand_rank)
     assert counters["population.h2d_bytes"] == sum(
         np.asarray(a).nbytes for a in operands)
-    # fetched as the kernel returns them: x, alpha, costs (int32), values,
-    # forced, cert
-    x, alpha, _, values, forced, _ = out
+    # fetched as the kernel returns them: x, costs (int32), values (R
+    # per-run float64 buffers), forced, cert; alpha is built on the host
+    x, _, _, values, forced, _ = out
     r, n = x.shape
     assert counters["population.d2h_bytes"] == (
-        x.nbytes + alpha.nbytes + r * n * 4 + values.nbytes
-        + forced.nbytes + r)
+        x.nbytes + r * n * 4 + values.nbytes + forced.nbytes + r)
 
 
 def test_prefilter_schedule_bit_identical_with_tracing_on_and_off():

@@ -17,6 +17,7 @@ from jax.sharding import SingleDeviceSharding
 
 from repro.configs.base import FeelConfig
 from repro.core import control as ctl
+from repro.core import population as pop
 from repro.federated.task import LM_TINY
 from repro.kernels.flash_attention import flash_attention
 from repro.kernels.robust_aggregate import _robust_call
@@ -27,6 +28,8 @@ from repro.kernels.weighted_aggregate import weighted_aggregate
 N_STACK, MLP_PARAMS = 64, 50_890
 # control plane: a 12-run sweep over the paper's K=50 UEs
 R_RUNS = 12
+# population prefilter: 5 stacked runs over a 4,096-UE pool, K=64, M=512
+POP_R, POP_N, POP_K, POP_M = 5, 4096, 64, 512
 
 
 @pytest.fixture(scope="module")
@@ -104,4 +107,28 @@ def test_control_schedule_kernel_compiles_in_f64(one_chip):
     x, alpha, costs, values, forced = outs
     assert x.shape == (R_RUNS, k) and x.dtype == np.bool_
     assert alpha.dtype == np.float64 and values.dtype == np.float64
+    assert compiled.as_text()
+
+
+def test_prefilter_kernel_compiles_in_f64(one_chip):
+    # the v5e compiler emulates float64 as float32 pairs and refuses some
+    # float64 ops (a bitcast to integer words among them): an output
+    # encoding it cannot lower fails here
+    with jax.enable_x64(True):
+        f64 = lambda *shape: _sds(one_chip, shape, jnp.float64)  # noqa: E731
+        pool = f64(POP_R, POP_N)
+        args = (_sds(one_chip, (POP_R,), jnp.int32),        # policy_id
+                pool, pool, pool, pool, pool, pool,         # rep..gains
+                _sds(one_chip, (POP_R, POP_N), jnp.int64),  # rand_rank
+                f64(POP_R), f64(POP_R), f64(3),             # w_rep/w_div/gamma
+                f64(), f64(), f64())                        # B, P, N0
+        statics = dict(k=POP_K, n_sel=FeelConfig().min_selected, m=POP_M)
+        compiled = pop._prefilter_kernel.lower(*args, **statics).compile()
+        outs = jax.eval_shape(
+            lambda *a: pop._prefilter_kernel(*a, **statics), *args)
+    x, costs, values, forced, cert = outs
+    assert x.shape == (POP_R, POP_N) and costs.dtype == np.int32
+    assert [(v.shape, v.dtype) for v in values] == [((POP_N,),
+                                                     np.float64)] * POP_R
+    assert forced.shape == cert.shape == (POP_R,)
     assert compiled.as_text()
