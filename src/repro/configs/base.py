@@ -32,6 +32,32 @@ class MoEConfig:
     # crosses shards (all-to-all). 0/1 = single global dispatch (SPMD-hostile
     # scatter; kept as the recorded baseline). See EXPERIMENTS.md §Perf.
     dispatch_groups: int = 0
+    # Routing rule. "softmax": softmax scores, top-k, the gates
+    # renormalised where ``normalize_gates``. "sigmoid": DeepSeek-V3's
+    # noaux_tc rule — sigmoid scores, top-k chosen on score + a per-expert
+    # selection bias (never trained by gradient: DeepSeek-V3 moves it by a
+    # balance rule of its own, which local training leaves out), the chosen
+    # scores renormalised (``normalize_gates``) and scaled by
+    # ``routed_scaling``.
+    scoring: str = "softmax"
+    routed_scaling: float = 1.0
+    # Compute path. False: capacity dropping (the sort-and-scatter layer;
+    # softmax scoring only). True: no token dropped — the expert-parallel
+    # layer, which routes over all ``n_routed`` experts, computes the part
+    # of the layer that experts [0, held_experts) add (0 = all of them)
+    # and weighs the sequence-wise balance loss by ``router_aux_weight``.
+    dropless: bool = False
+    held_experts: int = 0
+
+    def __post_init__(self):
+        if not self.dropless and (self.scoring != "softmax"
+                                  or self.held_experts):
+            raise ValueError("sigmoid scoring and held experts need the "
+                             "dropless layer (dropless=True)")
+
+    @property
+    def n_held(self) -> int:
+        return self.held_experts or self.n_routed
 
 
 @dataclass(frozen=True)
@@ -52,8 +78,9 @@ class SSMConfig:
 
 @dataclass(frozen=True)
 class MLAConfig:
-    """DeepSeek-V3 multi-head latent attention sub-config [arXiv:2412.19437]."""
-    q_lora_rank: int = 1536
+    """DeepSeek-V3 multi-head latent attention sub-config [arXiv:2412.19437].
+    ``q_lora_rank=None`` projects queries directly (Moonlight, V2-Lite)."""
+    q_lora_rank: Optional[int] = 1536
     kv_lora_rank: int = 512
     qk_nope_head_dim: int = 128
     qk_rope_head_dim: int = 64
@@ -192,7 +219,8 @@ class ModelConfig:
             if self.mla is not None:
                 m = self.mla
                 qk_hd = m.qk_nope_head_dim + m.qk_rope_head_dim
-                n = d * m.q_lora_rank + m.q_lora_rank * self.n_heads * qk_hd
+                n = (d * self.n_heads * qk_hd if m.q_lora_rank is None else
+                     d * m.q_lora_rank + m.q_lora_rank * self.n_heads * qk_hd)
                 n += d * (m.kv_lora_rank + m.qk_rope_head_dim)
                 n += m.kv_lora_rank * self.n_heads * (m.qk_nope_head_dim + m.v_head_dim)
                 n += self.n_heads * m.v_head_dim * d
@@ -223,7 +251,7 @@ class ModelConfig:
         m = self.moe
         per = self._dense_mlp_params(m.d_ff_expert)
         router = self.d_model * m.n_routed
-        n_exp = (m.top_k if active_only else m.n_routed) + m.n_shared
+        n_exp = (m.top_k if active_only else m.n_held) + m.n_shared
         return n_exp * per + router
 
 

@@ -5,9 +5,9 @@ from __future__ import annotations
 import dataclasses
 from typing import Dict, List
 
-from repro.configs.base import SHAPES, ModelConfig, MoEConfig, SSMConfig
+from repro.configs.base import SHAPES, ModelConfig, SSMConfig
 
-from repro.configs.moonshot_v1_16b_a3b import CONFIG as _moonshot
+from repro.configs.moonlight_16b_a3b import CONFIG as _moonlight
 from repro.configs.jamba_1_5_large_398b import CONFIG as _jamba
 from repro.configs.mamba2_370m import CONFIG as _mamba2
 from repro.configs.yi_34b import CONFIG as _yi
@@ -19,7 +19,7 @@ from repro.configs.qwen2_5_32b import CONFIG as _qwen25
 from repro.configs.deepseek_v3_671b import CONFIG as _deepseek
 
 ARCHS: Dict[str, ModelConfig] = {c.name: c for c in [
-    _moonshot, _jamba, _mamba2, _yi, _seamless,
+    _moonlight, _jamba, _mamba2, _yi, _seamless,
     _qwen_moe, _chameleon, _starcoder2, _qwen25, _deepseek,
 ]}
 
@@ -51,9 +51,10 @@ def reduced(cfg: ModelConfig) -> ModelConfig:
         encoder_layers=2 if cfg.encoder_layers else 0,
         block_len=0,
     )
-    if cfg.moe is not None:
-        kw["moe"] = MoEConfig(n_routed=4, top_k=2, d_ff_expert=128,
-                              n_shared=min(cfg.moe.n_shared, 1))
+    if cfg.moe is not None:       # keeps the routing rule (sigmoid or not)
+        kw["moe"] = dataclasses.replace(
+            cfg.moe, n_routed=4, top_k=2, d_ff_expert=128,
+            n_shared=min(cfg.moe.n_shared, 1), held_experts=0)
     if cfg.ssm is not None:
         kw["ssm"] = SSMConfig(d_state=32, head_dim=32, expand=2,
                               n_groups=1, chunk=32)
@@ -63,7 +64,8 @@ def reduced(cfg: ModelConfig) -> ModelConfig:
         kw["moe_layer_period"] = 2 if cfg.moe is not None else 1
     if cfg.mla is not None:
         from repro.configs.base import MLAConfig
-        kw["mla"] = MLAConfig(q_lora_rank=64, kv_lora_rank=32,
+        kw["mla"] = MLAConfig(q_lora_rank=cfg.mla.q_lora_rank and 64,
+                              kv_lora_rank=32,
                               qk_nope_head_dim=32, qk_rope_head_dim=16,
                               v_head_dim=32)
         kw["head_dim"] = 32

@@ -42,9 +42,19 @@ call whose weights span all buckets. ``cohort_train_multi`` is the
 multi-run variant (per-row parameters) used by the batched sweep runner in
 ``federated/simulation.py`` — seeds/policies become one more slice of the
 client axis.
+
+Streamed cohort: a model too large to hold one copy per cohort row (the
+Moonlight share, 2.27 GB a copy) trains one client at a time instead.
+``streams`` decides from the model's bytes and the cohort's padded rows
+against the device's memory; ``client_train`` runs one UE's local epochs
+as ``client_sgd_step`` calls that donate the local model's buffers,
+``upload_eval`` scores its upload for Eq. 1, and ``add_scaled`` folds the
+weighted upload into the running FedAvg sum — no (K, ...) stack exists.
 """
 from __future__ import annotations
 
+import functools
+import os
 from functools import partial
 
 import jax
@@ -195,9 +205,93 @@ def unstack(stacked_params, i: int):
 
 
 # ---------------------------------------------------------------------- #
+# Streamed cohort (one client at a time)
+# ---------------------------------------------------------------------- #
+STACK_SHARE = 0.5   # of device memory the stacked path may plan to fill
+
+
+@functools.lru_cache(maxsize=None)
+def device_memory_bytes() -> int:
+    """The default device's memory; the host's where the backend reports
+    none (the CPU)."""
+    stats = jax.devices()[0].memory_stats() or {}
+    if "bytes_limit" in stats:
+        return int(stats["bytes_limit"])
+    return os.sysconf("SC_PAGE_SIZE") * os.sysconf("SC_PHYS_PAGES")
+
+
+def streams(param_bytes: int, rows: int) -> bool:
+    """Whether a cohort of ``rows`` padded rows trains streamed: the
+    stacked path holds the global model and, per row, a model, its
+    gradient and the trained upload ((1 + 3 rows) x the model's bytes);
+    beyond ``STACK_SHARE`` of the device's memory the cohort streams."""
+    return (1 + 3 * rows) * param_bytes > STACK_SHARE * device_memory_bytes()
+
+
+@partial(jax.jit, static_argnames=("task",), donate_argnames=("params",))
+def client_sgd_step(task, params, data, mask, lr):
+    """One local step of one client; ``params`` is donated."""
+    return task.sgd_step(params, data, mask, lr)
+
+
+@partial(jax.jit, static_argnames=("task",))
+def client_metric(task, params, data, mask):
+    return task.local_metric(params, data, mask)
+
+
+@partial(jax.jit, static_argnames=("task",))
+def upload_eval(task, params, eval_inputs, y_units, mask):
+    """One upload's (correct, valid) counts on its UE's masked units."""
+    correct = (task.predict_units(params, eval_inputs)
+               == y_units).astype(jnp.float32)
+    return correct_counts(correct, mask)
+
+
+@jax.jit
+def _copy(params):
+    return jax.tree.map(jnp.copy, params)
+
+
+@jax.jit
+def scaled(params, w):
+    """w * params, accumulated in float32."""
+    return jax.tree.map(lambda p: (w * p.astype(jnp.float32)).astype(p.dtype),
+                        params)
+
+
+@partial(jax.jit, donate_argnums=(0,))
+def add_scaled(total, params, w):
+    """total + w * params in float32; ``total`` is donated."""
+    return jax.tree.map(
+        lambda t, p: (t.astype(jnp.float32)
+                      + w * p.astype(jnp.float32)).astype(t.dtype),
+        total, params)
+
+
+def client_train(task, params, batches, lr, epochs: int):
+    """One client's local epochs from ``params`` over its ``batches``
+    (a list of (data, mask) device pairs): returns (trained params, its
+    (correct, valid) local-metric counts, its step stats folded — summed
+    ``pairs``, largest ``load_max``), all still on the device."""
+    p = _copy(params)
+    stats = None
+    for _ in range(epochs):
+        for d, m in batches:
+            p, st = client_sgd_step(task, p, d, m, lr)
+            stats = st if stats is None else {
+                "pairs": stats["pairs"] + st["pairs"],
+                "load_max": jnp.maximum(stats["load_max"], st["load_max"])}
+    counts = None
+    for d, m in batches:
+        c = client_metric(task, p, d, m)
+        counts = c if counts is None else counts + c
+    return p, counts, stats
+
+
+# ---------------------------------------------------------------------- #
 # telemetry probe surface (DESIGN.md §14)
 # ---------------------------------------------------------------------- #
-# The four jitted entry points of the data plane. The sweep/serve drivers
+# The jitted entry points of the data plane. The sweep/serve drivers
 # snapshot their compile-cache sizes (obs.trace.jit_cache_size) as
 # compile-cache gauges at end of run.
 JITTED_ENTRY_POINTS = {
@@ -205,6 +299,9 @@ JITTED_ENTRY_POINTS = {
     "cohort_train_multi": cohort_train_multi,
     "cohort_eval": cohort_eval,
     "cohort_eval_rows": cohort_eval_rows,
+    "client_sgd_step": client_sgd_step,
+    "client_metric": client_metric,
+    "upload_eval": upload_eval,
 }
 
 

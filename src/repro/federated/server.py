@@ -27,6 +27,15 @@ Two execution engines implement Alg. 1 lines 9-14:
         correctness oracle (tests/test_cohort.py pins the engines to the
         same accuracy curve).
 
+The vectorized engine streams the cohort instead where one model copy
+per padded cohort row would not fit (``cohort.streams``: the model's
+bytes x rows against the device's memory; the MNIST MLP never streams).
+Each scheduled UE then trains, is scored for Eq. 1 and is folded into a
+running FedAvg sum, in selection order, and the global model is replaced
+once at the end of the round (``_run_cohort_streamed``). Robust defenses,
+the validation detector and model-space attacks need the stacked uploads
+and raise there.
+
 The padded device-resident client arrays live in a ``CohortData`` that can
 be shared by several servers running on the same (dataset, partition) —
 the batched sweep runner (federated/simulation.py::run_sweep) builds it
@@ -60,9 +69,10 @@ from repro.core import population
 from repro.core.scheduler import (Schedule, best_channel_schedule,
                                   max_count_schedule, random_schedule)
 from repro.data.partition import (ClientData, pad_clients,
-                                  pad_clients_bucketed)
+                                  pad_clients_bucketed, sample_arrays)
 from repro.federated import cohort
-from repro.federated.aggregation import fedavg, fedavg_stacked
+from repro.federated.aggregation import (fedavg, fedavg_stacked,
+                                         normalize_weights)
 from repro.federated.task import FeelTask, as_task
 from repro.models.common import count_accuracy
 from repro.obs import trace
@@ -357,6 +367,10 @@ class FeelServer:
         self.unavailable: Optional[np.ndarray] = None
         self.pad_waste: List[float] = []   # per-round padded/real sample ratio
         self.logs: List[RoundLog] = []
+        # streamed path: per-(UE, clean twin) device batches and per-UE
+        # Eq. 1 unit masks, placed on first use
+        self._stream_batches: Dict[Tuple[int, bool], list] = {}
+        self._stream_masks: Dict[int, jax.Array] = {}
 
     # ------------------------------------------------------------------ #
     def _omega(self, round_t: int) -> Tuple[float, float]:
@@ -692,6 +706,103 @@ class FeelServer:
                 sp.set(rows=int(2 * n_pad))
             return np.stack([acc[:n], acc[n_pad:n_pad + n]])
 
+    # -- streamed cohort (models too large to stack) -------------------- #
+    def _streams(self, n: int) -> bool:
+        """The one place the round picks its path: the stacked engine
+        unless a model copy per padded row would not fit."""
+        nbytes = sum(l.nbytes for l in jax.tree.leaves(self.params))
+        return cohort.streams(nbytes, cohort.pad_count(n, self._N_BUCKET))
+
+    def _check_streamable(self) -> None:
+        if self.defense.name != "none":
+            raise ValueError(
+                f"defense {self.defense.name!r} needs the stacked uploads; "
+                "the streamed cohort keeps none (model too large to stack)")
+        if self.scenario.model is not None:
+            raise ValueError(
+                f"scenario {self.scenario.name!r} attacks in model space, "
+                "which needs the stacked uploads; the streamed cohort keeps "
+                "none (model too large to stack)")
+        if self.cfg.mode != "sync":
+            raise ValueError("the streamed cohort aggregates as it trains; "
+                             "mode='async' needs the stacked uploads")
+
+    def _client_batches(self, k: int, clean: bool) -> list:
+        """UE ``k``'s (data, mask) device batches of ``batch_size``
+        samples (the last zero-padded, mask 0), its clean twin's where
+        ``clean``."""
+        key = (k, clean)
+        if key not in self._stream_batches:
+            c = self.clients[k]
+            arrays = sample_arrays(c.clean if clean else c.data)
+            n, b = c.size, self.batch_size
+            out = []
+            for i in range(0, n, b):
+                m = np.zeros(b, np.float32)
+                m[:min(b, n - i)] = 1.0
+                d = {f: np.zeros((b,) + a.shape[1:], a.dtype)
+                     for f, a in arrays.items()}
+                for f, a in arrays.items():
+                    d[f][:min(b, n - i)] = a[i:i + b]
+                out.append(jax.device_put((d, m)))
+            self._stream_batches[key] = out
+        return self._stream_batches[key]
+
+    def _client_mask(self, k: int) -> jax.Array:
+        if k not in self._stream_masks:
+            self._stream_masks[k] = jnp.asarray(self._test_mask_arr[k])
+        return self._stream_masks[k]
+
+    def _run_cohort_streamed(self, sel: np.ndarray, t: int):
+        """The round's cohort one UE at a time, in selection order: local
+        training from the global model (span ``train.client``), Eq. 1's
+        score of the upload (``eval.upload``), and the weighted upload
+        added into the FedAvg sum. Returns the sum in the uploads' place
+        with weights None (``_aggregate_uploads`` installs it); the small
+        outputs of every UE come back in one host read."""
+        self._check_streamable()
+        cfg = self.cfg
+        active = self.scenario.schedule.active(t, self._mal_mask,
+                                               self._mal_rank)
+        w = np.asarray(normalize_weights(self.sizes[sel]))
+        width = int(np.prod(jax.tree.leaves(
+            sample_arrays(self.clients[0].data))[0].shape[1:]))
+        total, small, tokens = None, [], 0
+        for i, k in enumerate(sel.tolist()):
+            c = self.clients[k]
+            batches = self._client_batches(
+                k, c.clean is not None and not bool(active[k]))
+            with trace.span("train.client") as sp:
+                up, counts, stats = cohort.client_train(
+                    self.task, self.params, batches, self.lr,
+                    cfg.local_epochs)
+                if trace.enabled():
+                    sp.set(ue=k, steps=len(batches) * cfg.local_epochs)
+            with trace.span("eval"):
+                with trace.span("eval.upload"):
+                    score = cohort.upload_eval(self.task, up, self._ex,
+                                               self._ey, self._client_mask(k))
+            total = (cohort.scaled(up, w[i]) if total is None
+                     else cohort.add_scaled(total, up, w[i]))
+            del up
+            small.append((counts, score, stats))
+            tokens += c.size * width * cfg.local_epochs
+        small = jax.device_get(small)
+        acc_local = count_accuracy(np.stack([s[0] for s in small]))
+        acc_test = count_accuracy(np.stack([s[1] for s in small]))
+        mal = active[sel]
+        if self.scenario.report is not None:
+            acc_local = self.scenario.report.apply(acc_local, mal)
+        if trace.enabled():
+            trace.counter_inc("cohort.streamed", len(sel))
+            trace.counter_inc("train.tokens", tokens)
+            pairs = float(sum(s[2]["pairs"] for s in small))
+            if pairs:
+                trace.counter_inc("moe.held_pairs", pairs)
+                trace.counter_inc("moe.held_load_max",
+                                  float(max(s[2]["load_max"] for s in small)))
+        return total, None, acc_local, acc_test, None
+
     # ------------------------------------------------------------------ #
     # Round phases. ``run_round`` composes them; the batched sweep runner
     # (federated/simulation.py) interleaves the phases of many runs so
@@ -785,6 +896,8 @@ class FeelServer:
             if trace.enabled():
                 sp.set(t=t, engine=self.engine, n=int(sel.size))
             if self.engine == "vectorized":
+                if self._streams(sel.size):
+                    return self._run_cohort_streamed(sel, t)
                 return self._run_cohort_vectorized(sel, t)
             return self._run_cohort_loop(sel, t)
 
@@ -794,7 +907,13 @@ class FeelServer:
         write point for both engines and both execution modes. ``uploads``
         is whatever ``_train_cohort`` returned (params list / padded
         stack); ``weights`` the aligned FedAvg weights, possibly
-        staleness-discounted by the async engine."""
+        staleness-discounted by the async engine. The streamed path hands
+        the finished FedAvg sum with weights None."""
+        if weights is None:
+            with trace.span("defense.aggregate"):
+                self.params = uploads
+                self._def_stats = dfs.DefenseStats()
+            return
         if self.engine == "vectorized":
             self._aggregate_cohort(sel, uploads, weights)
             return

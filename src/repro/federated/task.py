@@ -37,7 +37,7 @@ and ANY pytree model:
         ``models.mlp``), which is what pins the refactor to the golden
         curves.
 
-``TASKS`` registers the two concrete tasks:
+``TASKS`` registers three concrete tasks:
 
     mnist_mlp — the paper's §V protocol, bit-parity with the
         pre-task-abstraction stack.
@@ -49,6 +49,15 @@ and ANY pytree model:
         Zipf-Markov stream; domains play the non-IID role of MNIST
         labels for the partition, while quality statistics and eval
         masks are computed over the TOKENS.
+    moonlight_ft — the same task on one chip's share of Moonlight-16B-A3B
+        (``configs/moonlight_16b_a3b.py::ONE_CHIP``: MLA, 8 of 64 routed
+        experts held, the vocabulary's first eighth) at its published
+        widths: windows of 2,048 tokens, one window a local step, each
+        layer rematerialised in the backward pass. Too large to stack,
+        its cohort trains streamed (federated/cohort.py).
+
+The LM tasks also expose ``sgd_step``, one local step on one batch, which
+the streamed cohort path drives client by client.
 """
 from __future__ import annotations
 
@@ -60,6 +69,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.configs.base import ModelConfig
+from repro.configs.moonlight_16b_a3b import ONE_CHIP
 from repro.core.diversity import gini_simpson, gini_simpson_hist
 from repro.data.partition import (GROUP_SIZE, MAX_GROUPS, MIN_GROUPS,
                                   label_histogram, partition)
@@ -71,7 +81,7 @@ from repro.models.mlp import (mlp_accuracy, mlp_apply, mlp_correct_counts,
                               mlp_init, mlp_sgd_epoch_masked)
 from repro.models.transformer import (lm_correct_counts, lm_forward,
                                       lm_init, lm_loss, lm_sgd_epoch,
-                                      lm_sgd_epoch_masked)
+                                      lm_sgd_epoch_masked, lm_sgd_step)
 
 
 class FeelTask:
@@ -85,7 +95,8 @@ class FeelTask:
     Host/data plane:  generate_data, partition_clients, histogram, gini.
     Eval units:       unit_labels, unit_rows, eval_inputs, unit_targets.
     Device plane:     init_params, sgd_epoch, local_metric, predict_units,
-                      eval_loss (None when the task has no loss metric).
+                      eval_loss (None when the task has no loss metric);
+                      sgd_step where the cohort can stream (LmTask).
     Loop oracle:      local_train, eval_units_loop, global_metrics.
     Protocol knobs:   group_size/min_groups/max_groups (partition),
                       batch_size, default_lr, default_n_train/_n_test.
@@ -202,6 +213,16 @@ LM_TINY = ModelConfig(name="lm-tiny", family="dense", n_layers=2,
 
 
 @partial(jax.jit, static_argnums=0)
+def _lm_init(cfg, key):
+    return lm_init(key, cfg)
+
+
+@partial(jax.jit, static_argnums=0)
+def _lm_eval_loss(cfg, params, tokens):
+    return lm_loss(cfg, params, {"tokens": tokens})[0]
+
+
+@partial(jax.jit, static_argnums=0)
 def _lm_predict(cfg, params, tokens):
     """(W, S) tokens -> (W*(S-1),) greedy next-token predictions (units)."""
     logits, _, _, _ = lm_forward(cfg, params, tokens,
@@ -220,7 +241,8 @@ class LmTask(FeelTask):
     eval support masks — is computed over the TOKENS the model actually
     learns. Evaluation units are the held-out windows' next-token target
     positions; ``eval_loss`` adds the held-out per-token cross-entropy as
-    the global quality metric (RoundLog.global_loss).
+    the global quality metric (RoundLog.global_loss). ``remat``
+    rematerialises each layer in local training's backward pass.
     """
     name: str = "lm_tiny"
     model: ModelConfig = LM_TINY
@@ -233,6 +255,7 @@ class LmTask(FeelTask):
     default_lr: float = 0.3
     default_n_train: int = 2_000
     default_n_test: int = 400
+    remat: bool = False
 
     @property
     def n_symbols(self) -> int:
@@ -281,11 +304,16 @@ class LmTask(FeelTask):
 
     # -- device plane (static under jit) ---------------------------------- #
     def init_params(self, key):
-        return lm_init(key, self.model)
+        """Seeded weights, made on the device in one program."""
+        return _lm_init(self.model, key)
 
     def sgd_epoch(self, params, d, m, lr, batch_size: int):
         return lm_sgd_epoch_masked(self.model, params, d["tokens"], m, lr,
-                                   batch_size)
+                                   batch_size, self.remat)
+
+    def sgd_step(self, params, d, m, lr):
+        return lm_sgd_step(self.model, params, d["tokens"], m, lr,
+                           self.remat)
 
     def local_metric(self, params, d, m):
         return lm_correct_counts(self.model, params, d["tokens"], m)
@@ -297,7 +325,7 @@ class LmTask(FeelTask):
 
     def eval_loss(self, params, ei):
         """Held-out per-token cross-entropy (the LM quality metric)."""
-        return lm_loss(self.model, params, {"tokens": ei["tokens"]})[0]
+        return _lm_eval_loss(self.model, params, ei["tokens"])
 
     # -- loop oracle (host) ------------------------------------------------ #
     def local_train(self, client, global_params, epochs: int, lr: float,
@@ -349,7 +377,17 @@ def _f32_masked_acc(correct: np.ndarray, m: np.ndarray) -> float:
     return float(count_accuracy([(correct & m).sum(), m.sum()]))
 
 
-TASKS = {t.name: t for t in (MnistTask(), LmTask())}
+# One chip's share of Moonlight-16B-A3B fine-tuned over a FEEL cell: 1-4
+# windows of 2,048 tokens per UE, one window a local step (the deployment's
+# batch: under even routing each expert here would see ~192 token slots a
+# step, as it would there). Plain SGD at 0.01: at 0.05 the random share's
+# local loss diverges within 4 steps.
+MOONLIGHT_FT = LmTask(name="moonlight_ft", model=ONE_CHIP, seq=2048,
+                      n_domains=10, group_size=1, min_groups=1, max_groups=4,
+                      batch_size=1, default_lr=0.01, default_n_train=50,
+                      default_n_test=2, remat=True)
+
+TASKS = {t.name: t for t in (MnistTask(), LmTask(), MOONLIGHT_FT)}
 
 
 def as_task(spec) -> FeelTask:

@@ -110,8 +110,9 @@ def stacked_cache_init(cfg, batch: int, cache_len: int, n_blocks=None,
 # ---------------------------------------------------------------------- #
 def layer_apply(cfg, p, kind, h, *, window=None, enc_out=None,
                 return_cache=False):
-    """Pre-norm layer. Returns (h, aux, cache)."""
-    aux = 0.0
+    """Pre-norm layer. Returns (h, aux, cache); ``aux`` is the expert
+    layer's stats (models/moe.py), zeros where the layer has none."""
+    aux = moe_mod.aux_stats()
     cache: dict = {}
     hin = rms_norm(h, p["norm1"], cfg.norm_eps)
     if kind["mixer"] == "attn":
@@ -145,12 +146,12 @@ def layer_apply(cfg, p, kind, h, *, window=None, enc_out=None,
 
 
 def block_apply(cfg, bp, h, *, window=None, enc_out=None, return_cache=False):
-    aux_total = 0.0
+    aux_total = moe_mod.aux_stats()
     caches = []
     for p, kind in zip(bp["layers"], cfg.block_pattern()):
         h, aux, cache = layer_apply(cfg, p, kind, h, window=window,
                                     enc_out=enc_out, return_cache=return_cache)
-        aux_total += aux
+        aux_total = moe_mod.add_aux(aux_total, aux)
         caches.append(cache)
     return h, aux_total, {"layers": tuple(caches)}
 
@@ -162,11 +163,12 @@ def scan_blocks(cfg, stacked, h, *, window=None, enc_out=None,
         h, aux = carry
         h2, a, cache = block_apply(cfg, bp, h, window=window, enc_out=enc_out,
                                    return_cache=return_cache)
-        return (h2, aux + a), (cache if return_cache else 0.0)
+        return (h2, moe_mod.add_aux(aux, a)), (cache if return_cache
+                                                else 0.0)
 
     if remat:
         body = jax.checkpoint(body)
-    (h, aux), caches = jax.lax.scan(body, (h, 0.0), stacked,
+    (h, aux), caches = jax.lax.scan(body, (h, moe_mod.aux_stats()), stacked,
                                     unroll=cfg.scan_unroll)
     return h, aux, (caches if return_cache else None)
 

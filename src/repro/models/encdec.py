@@ -84,7 +84,8 @@ def encdec_forward(cfg, params, src, tokens, *, remat=False,
 def encdec_loss(cfg, params, batch, *, remat=False):
     logits, aux, _, _ = encdec_forward(cfg, params, batch["src"],
                                        batch["tokens"], remat=remat)
-    loss = cross_entropy(logits[:, :-1], batch["tokens"][:, 1:]) + aux
+    loss = (cross_entropy(logits[:, :-1], batch["tokens"][:, 1:])
+            + aux["balance"])
     return loss, {"ce": loss}
 
 

@@ -1,7 +1,8 @@
 """Multi-head Latent Attention (DeepSeek-V3, arXiv:2412.19437).
 
 Faithful low-rank structure: compressed KV latent ``c_kv`` (kv_lora_rank) +
-decoupled shared RoPE key (qk_rope_head_dim). Prefill/train expands the latent;
+decoupled shared RoPE key (qk_rope_head_dim); queries through the low-rank
+``q_lora_rank`` path, or one direct projection where it is None. Prefill/train expands the latent;
 decode uses the *absorbed* formulation (W_uk folded into the query, W_uv applied
 after attention) so the cache holds only (kv_lora_rank + rope_dim) per token —
 the actual MLA memory win, visible in dry-run cache bytes.
@@ -20,10 +21,14 @@ def mla_init(key, cfg):
     qk_hd = m.qk_nope_head_dim + m.qk_rope_head_dim
     ks = jax.random.split(key, 8)
     dt = dtype_of(cfg)
+    if m.q_lora_rank is None:            # direct query projection
+        q = {"wq": dense_init(ks[0], (d, H * qk_hd), dt)}
+    else:
+        q = {"wdq": dense_init(ks[0], (d, m.q_lora_rank), dt),
+             "q_norm": ones((m.q_lora_rank,), dt),
+             "wuq": dense_init(ks[1], (m.q_lora_rank, H * qk_hd), dt)}
     return {
-        "wdq": dense_init(ks[0], (d, m.q_lora_rank), dt),
-        "q_norm": ones((m.q_lora_rank,), dt),
-        "wuq": dense_init(ks[1], (m.q_lora_rank, H * qk_hd), dt),
+        **q,
         "wdkv": dense_init(ks[2], (d, m.kv_lora_rank), dt),
         "kv_norm": ones((m.kv_lora_rank,), dt),
         "wkr": dense_init(ks[3], (d, m.qk_rope_head_dim), dt),
@@ -38,8 +43,11 @@ def _queries(cfg, p, x, positions):
     m = cfg.mla
     B, S, _ = x.shape
     H = cfg.n_heads
-    cq = rms_norm(x @ p["wdq"], p["q_norm"], cfg.norm_eps)
-    q = (cq @ p["wuq"]).reshape(B, S, H, m.qk_nope_head_dim + m.qk_rope_head_dim)
+    if "wq" in p:
+        q = x @ p["wq"]
+    else:
+        q = rms_norm(x @ p["wdq"], p["q_norm"], cfg.norm_eps) @ p["wuq"]
+    q = q.reshape(B, S, H, m.qk_nope_head_dim + m.qk_rope_head_dim)
     q_nope, q_rope = jnp.split(q, [m.qk_nope_head_dim], axis=-1)
     q_rope = apply_rope(q_rope, positions, cfg.rope_theta)
     return q_nope, q_rope

@@ -13,6 +13,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.models import blocks as blk
+from repro.models import moe as moe_mod
 from repro.models.common import (correct_counts, cross_entropy, dtype_of,
                                  embed_init, ones, rms_norm, dense_init)
 from repro.sharding.ctx import constrain
@@ -58,16 +59,16 @@ def lm_forward(cfg, params, tokens, *, window=None, remat=False,
     """tokens (B,S) int32 -> (logits (B,S,V), aux, cache|None, h_last)."""
     h = params["embed"][tokens].astype(dtype_of(cfg))
     h = constrain(h, "act")
-    aux = 0.0
+    aux = moe_mod.aux_stats()
     head_caches = []
     for p in params.get("head_layers", ()):
         h, a, c = blk.layer_apply(cfg, p, _head_kind(), h, window=window,
                                   return_cache=return_cache)
-        aux += a
+        aux = moe_mod.add_aux(aux, a)
         head_caches.append(c)
     h, a, caches = blk.scan_blocks(cfg, params["blocks"], h, window=window,
                                    return_cache=return_cache, remat=remat)
-    aux += a
+    aux = moe_mod.add_aux(aux, a)
     hn = rms_norm(h, params["final_norm"], cfg.norm_eps)
     logits = constrain(hn @ params["lm_head"], "logits")
     cache = None
@@ -95,12 +96,12 @@ def lm_loss(cfg, params, batch, *, remat=False):
         z = z @ mtp["proj"]
         z, a2, _ = blk.layer_apply(cfg, mtp["layer"], _head_kind(), z,
                                    window=window)
-        aux += a2
+        aux = moe_mod.add_aux(aux, a2)
         mtp_logits = rms_norm(z, params["final_norm"], cfg.norm_eps) @ params["lm_head"]
         mtp_loss = cross_entropy(mtp_logits[:, :-2], tokens[:, 2:])
         loss = loss + 0.3 * mtp_loss
         metrics["mtp_ce"] = mtp_loss
-    loss = loss + aux
+    loss = loss + aux["balance"]
     metrics["aux"] = aux
     return loss, metrics
 
@@ -130,15 +131,16 @@ def lm_loss_masked(cfg, params, batch, *, remat=False):
     invariant to their token content and their gradient contribution is
     exactly zero (a fully padded batch is a strict parameter no-op). For a
     fully valid batch the masked mean reduces to the plain ``lm_loss``
-    (weight sum == target count). MoE router aux is NOT masked — use
-    aux-free (dense/ssm) configs for the federated task.
+    (weight sum == target count). The MoE router's auxiliary loss is NOT
+    masked: a padded window still routes, so MoE models train on batches
+    of real windows only (the streamed cohort's one-window steps).
     """
     tokens = batch["tokens"]
     logits, aux, _, _ = lm_forward(cfg, params, tokens,
                                    window=cfg.sliding_window, remat=remat)
     w = _token_weights(tokens, batch["m"])
     loss = cross_entropy(logits[:, :-1], tokens[:, 1:], mask=w)
-    return loss + aux, {"ce": loss, "aux": aux}
+    return loss + aux["balance"], {"ce": loss, "aux": aux}
 
 
 def lm_correct_counts(cfg, params, tokens, m):
@@ -171,8 +173,9 @@ def lm_sgd_epoch(cfg, params, tokens, lr, batch_size: int = 8):
     return params
 
 
-@partial(jax.jit, static_argnums=(0, 5))
-def lm_sgd_epoch_masked(cfg, params, tokens, m, lr, batch_size: int = 8):
+@partial(jax.jit, static_argnums=(0, 5, 6))
+def lm_sgd_epoch_masked(cfg, params, tokens, m, lr, batch_size: int = 8,
+                        remat: bool = False):
     """Masked twin of ``lm_sgd_epoch`` over a padded window set.
 
     tokens (S, seq), m (S,) with S a multiple of batch_size; batches that
@@ -190,12 +193,24 @@ def lm_sgd_epoch_masked(cfg, params, tokens, m, lr, batch_size: int = 8):
     def body(params, batch):
         bt, bm = batch
         g = jax.grad(lambda p: lm_loss_masked(
-            cfg, p, {"tokens": bt, "m": bm})[0])(params)
+            cfg, p, {"tokens": bt, "m": bm}, remat=remat)[0])(params)
         params = jax.tree.map(lambda p, gg: p - lr * gg, params, g)
         return params, 0.0
 
     params, _ = jax.lax.scan(body, params, (tb, mb))
     return params
+
+
+def lm_sgd_step(cfg, params, tokens, m, lr, remat: bool = False):
+    """One masked SGD step on a batch of windows: (params, stats) where
+    ``stats`` holds the expert layers' ``pairs`` and ``load_max`` for the
+    step (zeros for models without them)."""
+    def loss(p):
+        return lm_loss_masked(cfg, p, {"tokens": tokens, "m": m},
+                              remat=remat)
+    (_, metrics), g = jax.value_and_grad(loss, has_aux=True)(params)
+    params = jax.tree.map(lambda p, gg: p - lr * gg, params, g)
+    return params, {k: metrics["aux"][k] for k in ("pairs", "load_max")}
 
 
 # ---------------------------------------------------------------------- #

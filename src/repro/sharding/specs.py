@@ -39,7 +39,8 @@ _COL = {"wq", "wk", "wv", "wg", "wu", "in_proj", "wuq", "wuk", "wuv", "wdq",
 _ROW = {"wo", "wd", "out_proj"}
 _VEC_MODEL = {"bq", "bk", "bv", "conv_b", "A_log", "D", "dt_bias"}
 _REPL = {"router", "wkr", "wdkv", "norm1", "norm2", "norm_x", "final_norm",
-         "enc_norm", "q_norm", "k_norm", "kv_norm", "norm_h", "norm_e"}
+         "enc_norm", "q_norm", "k_norm", "kv_norm", "norm_h", "norm_e",
+         "e_bias"}
 
 
 def _path_names(path) -> list:

@@ -32,7 +32,8 @@ def test_grouped_equals_global_no_drops(g, seed):
     yg, ag = moe_mod.moe_apply(cfgg, p, x)
     np.testing.assert_allclose(np.asarray(y0), np.asarray(yg),
                                atol=1e-5, rtol=1e-5)
-    np.testing.assert_allclose(float(a0), float(ag), rtol=1e-5)
+    np.testing.assert_allclose(float(a0["balance"]), float(ag["balance"]),
+                               rtol=1e-5)
 
 
 def test_grouped_finite_under_drops():
@@ -41,7 +42,7 @@ def test_grouped_finite_under_drops():
     x = jax.random.normal(jax.random.PRNGKey(1), (8, 16, cfg.d_model))
     y, aux = moe_mod.moe_apply(cfg, p, x)
     assert bool(jnp.all(jnp.isfinite(y)))
-    assert bool(jnp.isfinite(aux))
+    assert bool(jnp.isfinite(aux["balance"]))
 
 
 def test_grouped_gradients_flow():
@@ -51,7 +52,7 @@ def test_grouped_gradients_flow():
 
     def loss(p):
         y, aux = moe_mod.moe_apply(cfg, p, x)
-        return jnp.sum(jnp.square(y)) + aux
+        return jnp.sum(jnp.square(y)) + aux["balance"]
 
     g = jax.grad(loss)(p)
     norms = [float(jnp.linalg.norm(v.astype(jnp.float32)))
@@ -72,3 +73,18 @@ def test_capacity_rounding():
     cfg = _cfg()
     assert moe_mod.capacity(64, cfg) % 8 == 0
     assert moe_mod.capacity(1, cfg) >= 8
+
+
+def test_capacity_layer_reports_no_held_expert_work():
+    """The capacity layer's stats carry its Switch balance loss and zero
+    held-expert pairs and load, so layer stacks combine them with the
+    dropless layer's alike."""
+    cfg = _cfg(groups=0)
+    p = moe_mod.moe_init(jax.random.PRNGKey(0), cfg)
+    x = jax.random.normal(jax.random.PRNGKey(3), (2, 8, cfg.d_model))
+    _, st = moe_mod.moe_apply(cfg, p, x)
+    assert set(st) == {"balance", "pairs", "load_max"}
+    assert float(st["balance"]) > 0
+    assert float(st["pairs"]) == 0 and float(st["load_max"]) == 0
+    both = moe_mod.add_aux(st, st)
+    assert float(both["balance"]) == 2 * float(st["balance"])

@@ -424,9 +424,11 @@ def test_write_bench_json_attaches_phase_summary(tmp_path):
 
 def test_configure_env_equivalent_and_reset(tmp_path):
     tr = trace.configure(enabled=True, ring_size=8)
-    for i in range(20):
-        with trace.span(f"s{i}"):
-            pass
-    assert len(tr.spans) <= 8                 # ring bounded
-    trace.configure(enabled=False)
+    try:
+        for i in range(20):
+            with trace.span(f"s{i}"):
+                pass
+        assert len(tr.spans) <= 8                 # ring bounded
+    finally:
+        trace.configure(enabled=False, ring_size=trace._RING)
     assert tr.spans == [] and tr.enabled is False

@@ -132,3 +132,28 @@ def test_prefilter_kernel_compiles_in_f64(one_chip):
                                                      np.float64)] * POP_R
     assert forced.shape == cert.shape == (POP_R,)
     assert compiled.as_text()
+
+
+def test_moonlight_client_step_compiles_at_published_widths(one_chip):
+    """The streamed cohort's local step on one chip's share of
+    Moonlight-16B-A3B (568.5 M float32 parameters; MLA, 8 held experts
+    through ragged products, per-layer remat) at one 2,048-token window.
+    Its arguments are one model, donated to its output: the program has
+    no cohort axis, so its memory does not grow with the cohort."""
+    from repro.federated import cohort
+    from repro.federated.task import MOONLIGHT_FT as task
+    params = jax.tree.map(
+        lambda s: _sds(one_chip, s.shape, s.dtype),
+        jax.eval_shape(task.init_params, jax.random.PRNGKey(0)))
+    nbytes = sum(l.size * l.dtype.itemsize for l in jax.tree.leaves(params))
+    with jax.default_matmul_precision("default"):
+        compiled = cohort.client_sgd_step.lower(
+            task, params, {"tokens": _sds(one_chip, (1, task.seq), jnp.int32)},
+            _sds(one_chip, (1,), jnp.float32),
+            _sds(one_chip, (), jnp.float32)).compile()
+    mem = compiled.memory_analysis()
+    assert nbytes == 568_484_608 * 4
+    assert mem.argument_size_in_bytes - nbytes < 2**20
+    assert mem.alias_size_in_bytes >= nbytes          # params donated
+    # gradients plus one layer's rematerialised activations
+    assert mem.temp_size_in_bytes < 8e9
