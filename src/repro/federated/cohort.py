@@ -38,7 +38,11 @@ so the server splits a round's cohort into 2-3 ``max_samples`` buckets
 (``data.partition.bucket_levels`` — quantized so compiles stay cached),
 trains each bucket with ``cohort_train``, and merges the per-bucket stacks
 back into selection order (``merge_stacks``) for ONE ``fedavg_stacked``
-call whose weights span all buckets. ``cohort_train_multi`` is the
+call whose weights span all buckets. The server's round runs that glue
+as two programs around the buckets' ``cohort_train`` calls:
+``gather_buckets`` gathers every bucket's rows, and ``assemble_cohort``
+merges the trained rows into selection order, pads the cohort and
+gathers its eval masks. ``cohort_train_multi`` is the
 multi-run variant (per-row parameters) used by the batched sweep runner in
 ``federated/simulation.py`` — seeds/policies become one more slice of the
 client axis.
@@ -155,6 +159,34 @@ def pad_stacked(stacked, n_total: int):
         return jnp.concatenate(
             [l, jnp.zeros((n_total - n,) + l.shape[1:], l.dtype)], axis=0)
     return jax.tree.map(pad, stacked)
+
+
+@jax.jit
+def gather_buckets(buckets, rows):
+    """One round's training inputs: bucket b's (data pytree, mask) rows
+    ``rows[b]``, gathered on the device for every bucket in one program."""
+    return tuple(jax.tree.map(lambda a, r=r: jnp.take(a, r, axis=0), bkt)
+                 for bkt, r in zip(buckets, rows))
+
+
+@jax.jit
+def assemble_cohort(stacks, counts, take, masks, mask_rows):
+    """The trained buckets as one padded cohort, in one program.
+
+    stacks / counts — per-bucket ``cohort_train`` outputs (padded rows
+    included); take (n_pad,) — each cohort row's row in the buckets'
+    concatenation, in selection order, with pad rows pointing past its
+    end (filled with zeros: weight-0, all-zero-mask null rows); masks
+    (K+1, U) — the per-UE eval unit masks, gathered at ``mask_rows``.
+    The same data movement as ``merge_stacks`` + ``pad_stacked`` and a
+    mask gather, so the values are bit-equal. Returns (stacked (n_pad,
+    ...), local-metric counts (n_pad, 2), eval masks (n_pad, U)).
+    """
+    def merge(*parts):
+        return jnp.take(jnp.concatenate(parts), take, axis=0, mode="fill",
+                        fill_value=0)
+    return (jax.tree.map(merge, *stacks), merge(*counts),
+            jnp.take(masks, mask_rows, axis=0))
 
 
 def broadcast_params(params, n: int):
@@ -297,6 +329,8 @@ def client_train(task, params, batches, lr, epochs: int):
 JITTED_ENTRY_POINTS = {
     "cohort_train": cohort_train,
     "cohort_train_multi": cohort_train_multi,
+    "gather_buckets": gather_buckets,
+    "assemble_cohort": assemble_cohort,
     "cohort_eval": cohort_eval,
     "cohort_eval_rows": cohort_eval_rows,
     "client_sgd_step": client_sgd_step,

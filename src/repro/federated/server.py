@@ -226,7 +226,7 @@ class FeelServer:
     None defers to ``cfg.defense`` (default ``"none"``).
 
     The underscore round-phase methods (_schedule_round, _cohort_parts,
-    _merge_cohort, _apply_attacks, _eval_masks, _aggregate_cohort,
+    _apply_attacks, _eval_masks, _aggregate_cohort,
     _finalize_round, _log_round, draw_control_inputs) are a semi-public
     contract: the batched sweep runner (federated/simulation.py)
     interleaves them across runs — change their signatures and the sweep
@@ -520,24 +520,6 @@ class FeelServer:
                                    rows.dtype)])
             yield bkt, pos, rows
 
-    def _gather_bucket(self, bkt: Dict, rows: np.ndarray):
-        """Device-side gather of a bucket's (data pytree, mask) rows."""
-        idx = jnp.asarray(rows)
-        return ({f: jnp.take(a, idx, axis=0)
-                 for f, a in bkt["data"].items()},
-                jnp.take(bkt["mask"], idx, axis=0))
-
-    @staticmethod
-    def _merge_cohort(parts):
-        """Merge per-bucket results (pos, stacked_real_rows, acc_real) back
-        into selection order: FedAvg then accumulates in exactly the order
-        the loop oracle uses (bit-for-bit parity)."""
-        order = np.concatenate([p[0] for p in parts])
-        inv = np.argsort(order, kind="stable")
-        stacked = cohort.merge_stacks([p[1] for p in parts], inv)
-        acc_local = np.concatenate([p[2] for p in parts])[inv]
-        return stacked, acc_local
-
     def _active_malicious(self, sel: np.ndarray, t: int) -> np.ndarray:
         """(len(sel),) bool — scheduled UEs whose malicious behaviour is
         ACTIVE in round t (the scenario's activity schedule gates the
@@ -559,18 +541,22 @@ class FeelServer:
         """Model poisoning + dishonest reporting on the merged stack:
         ONE masked ``tree_map`` over the malicious rows
         (``ModelAttack.apply_stacked``) — no per-malicious-client
-        dispatch. ``_apply_attacks_loop`` keeps the replaced per-client
-        ``.at[i].set`` loop as the parity oracle (tests/test_attacks.py
-        pins them bit-for-bit equal)."""
+        dispatch. The stack may carry pad rows past ``sel``; they pass
+        through. ``acc_local`` None leaves the report to
+        ``_apply_report`` (the vectorized engine reads the counts after
+        the uploads' eval). ``_apply_attacks_loop`` keeps the replaced
+        per-client ``.at[i].set`` loop as the parity oracle
+        (tests/test_attacks.py pins them bit-for-bit equal)."""
         scn = self.scenario
         with trace.span("attack.apply") as sp:
             ref = self._attack_ref_params()
             mal = self._active_malicious(sel, t)
             if scn.model is not None and mal.any():
-                stacked = scn.model.apply_stacked(stacked, self.params,
-                                                  mal, ref)
-            if scn.report is not None:
-                acc_local = scn.report.apply(acc_local, mal)
+                rows = jax.tree.leaves(stacked)[0].shape[0]
+                stacked = scn.model.apply_stacked(
+                    stacked, self.params, np.pad(mal, (0, rows - mal.size)),
+                    ref)
+            acc_local = self._apply_report(sel, acc_local, t)
             if trace.enabled():
                 sp.set(scenario=scn.name, n_active=int(mal.sum()))
         return stacked, acc_local
@@ -588,9 +574,15 @@ class FeelServer:
                     self.params, cohort.unstack(stacked, int(i)), ref)
                 stacked = jax.tree.map(
                     lambda l, p, i=int(i): l.at[i].set(p), stacked, poisoned)
-        if scn.report is not None:
-            acc_local = scn.report.apply(acc_local, mal)
-        return stacked, acc_local
+        return stacked, self._apply_report(sel, acc_local, t)
+
+    def _apply_report(self, sel, acc_local, t):
+        """Dishonest reporting on the host's local accuracies (None passes
+        through)."""
+        scn = self.scenario
+        if scn.report is None or acc_local is None:
+            return acc_local
+        return scn.report.apply(acc_local, self._active_malicious(sel, t))
 
     def _eval_masks(self, sel: np.ndarray, n_pad: int) -> jax.Array:
         """(n_pad, T) per-UE eval masks for the padded merged stack."""
@@ -632,49 +624,67 @@ class FeelServer:
                     self.cfg.n_malicious)
 
     def _run_cohort_vectorized(self, sel: np.ndarray, t: int):
+        """The stacked round as a few programs and one host read: one
+        gather of every bucket's rows, ``cohort_train`` per bucket, one
+        ``assemble_cohort`` (merge into selection order, pad to a stable
+        row count, gather the eval masks), ``cohort_eval``; the local
+        and test counts then come back together."""
         cfg = self.cfg
         cd = self._ensure_cohort_data()
         n = sel.size
-        parts, pad_slots = [], 0
-        for bkt, pos, rows in self._cohort_parts(sel, t):
-            data, ms = self._gather_bucket(bkt, rows)
+        n_pad = cohort.pad_count(n, self._N_BUCKET)
+        parts = list(self._cohort_parts(sel, t))
+        # cohort row i <- row take[i] of the buckets' concatenated stacks;
+        # pad rows point past its end, and the merge fills them with zeros
+        # (null rows: all-zero eval mask, weight 0)
+        starts = np.cumsum([0] + [rows.size for _, _, rows in parts])
+        take = np.full(n_pad, starts[-1])
+        for (_, pos, _), start in zip(parts, starts):
+            take[pos] = start + np.arange(pos.size)
+        mask_rows = np.concatenate(
+            [sel, np.full(n_pad - n, len(self.clients), sel.dtype)])
+        rows, take, mask_rows = jax.device_put(
+            ([r for _, _, r in parts], take, mask_rows))
+        batches = cohort.gather_buckets(
+            tuple((b["data"], b["mask"]) for b, _, _ in parts), tuple(rows))
+        stacks, counts, pad_slots = [], [], 0
+        for (bkt, pos, rows_b), (data, ms) in zip(parts, batches):
             with trace.span("train.bucket") as bsp:
                 probe0 = trace.compiles()
                 stacked_b, acc_b = cohort.cohort_train(
                     self.task, self.params, data, ms, self.lr,
                     cfg.local_epochs, self.batch_size)
                 if trace.enabled():
-                    bsp.set(level=int(bkt["level"]), rows=int(rows.size),
+                    bsp.set(level=int(bkt["level"]), rows=int(rows_b.size),
                             real=int(pos.size),
                             compiled=trace.compiles() > probe0)
                     trace.observe("train.bucket_occupancy",
-                                  pos.size / rows.size)
-            parts.append((pos,
-                          jax.tree.map(lambda l, m=pos.size: l[:m],
-                                       stacked_b),
-                          count_accuracy(acc_b)[:pos.size]))
-            pad_slots += rows.size * bkt["level"]
-        stacked, acc_local = self._merge_cohort(parts)
+                                  pos.size / rows_b.size)
+            stacks.append(stacked_b)
+            counts.append(acc_b)
+            pad_slots += rows_b.size * bkt["level"]
+        stacked_p, local_counts, eval_masks = cohort.assemble_cohort(
+            tuple(stacks), tuple(counts), take, cd.mask_dev, mask_rows)
         self.pad_waste.append(
             float(pad_slots) / max(float(cd.sizes[sel].sum()), 1.0))
         if trace.enabled():
             trace.observe("train.pad_waste", self.pad_waste[-1])
 
-        stacked, acc_local = self._apply_attacks(sel, stacked, acc_local, t)
+        stacked_p, _ = self._apply_attacks(sel, stacked_p, None, t)
 
-        # evaluate + aggregate once on the merged stack, zero-padded to a
-        # stable row count (null rows score 0 under an all-zero mask and
-        # contribute exactly 0 with weight 0)
-        n_pad = cohort.pad_count(n, self._N_BUCKET)
-        stacked_p = cohort.pad_stacked(stacked, n_pad)
         with trace.span("eval") as esp:
             probe0 = trace.compiles()
-            acc_test = count_accuracy(
-                cohort.cohort_eval(self.task, stacked_p, self._ex, self._ey,
-                                   self._eval_masks(sel, n_pad)))[:n]
+            test_counts = cohort.cohort_eval(self.task, stacked_p, self._ex,
+                                             self._ey, eval_masks)
+            local_counts, test_counts = jax.device_get(
+                (local_counts, test_counts))
+            trace.counter_inc("data.host_reads")
             if trace.enabled():
                 esp.set(rows=int(n_pad),
                         compiled=trace.compiles() > probe0)
+        acc_local = self._apply_report(sel, count_accuracy(local_counts)[:n],
+                                       t)
+        acc_test = count_accuracy(test_counts)[:n]
         acc_val = self._eval_validation(stacked_p, sel)
         return (stacked_p, self._cohort_weights(sel, stacked_p),
                 acc_local, acc_test, acc_val)
@@ -702,6 +712,7 @@ class FeelServer:
             acc = count_accuracy(
                 cohort.cohort_eval(self.task, both, self._ex, self._ey,
                                    jnp.concatenate([vm, vm])))
+            trace.counter_inc("data.host_reads")
             if trace.enabled():
                 sp.set(rows=int(2 * n_pad))
             return np.stack([acc[:n], acc[n_pad:n_pad + n]])

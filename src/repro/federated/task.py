@@ -76,12 +76,13 @@ from repro.data.partition import (GROUP_SIZE, MAX_GROUPS, MIN_GROUPS,
 from repro.data.synthetic_mnist import N_CLASSES, generate
 from repro.data.tokens import make_windows
 from repro.federated.client import ClientReport, local_train
-from repro.models.common import count_accuracy
+from repro.models.common import correct_counts, count_accuracy
 from repro.models.mlp import (mlp_accuracy, mlp_apply, mlp_correct_counts,
                               mlp_init, mlp_sgd_epoch_masked)
 from repro.models.transformer import (lm_correct_counts, lm_forward,
                                       lm_init, lm_loss, lm_sgd_epoch,
                                       lm_sgd_epoch_masked, lm_sgd_step)
+from repro.obs import trace
 
 
 class FeelTask:
@@ -188,19 +189,22 @@ class MnistTask(FeelTask):
 
     def global_metrics(self, params, test, ei, ey, watch_class,
                        watch_target):
-        """(global_acc, global_loss, source_acc, attack_success)."""
-        g_acc = float(mlp_accuracy(params, ei["x"], ey))
-        src_acc = atk_succ = float("nan")
-        if watch_class is not None:
-            m = test.y == watch_class
-            if m.any():
-                xs = jnp.asarray(test.x[m])
-                src_acc = float(mlp_accuracy(
-                    params, xs, jnp.asarray(test.y[m])))
-                if watch_target is not None:
-                    tgt = jnp.full(int(m.sum()), watch_target, ey.dtype)
-                    atk_succ = float(mlp_accuracy(params, xs, tgt))
-        return g_acc, float("nan"), src_acc, atk_succ
+        """(global_acc, global_loss, source_acc, attack_success): one
+        program on the device-resident test set, one host read of its
+        counts. NaN source accuracy without a watched class or where the
+        test set has none of it; NaN attack success without a target."""
+        counts = jax.device_get(_mlp_global_counts(
+            params, ei["x"], ey,
+            -1 if watch_class is None else int(watch_class),
+            -1 if watch_target is None else int(watch_target)))
+        trace.counter_inc("data.host_reads")
+        g_acc, src_acc, atk_succ = (float(a) for a in count_accuracy(counts))
+        nan = float("nan")
+        if watch_class is None or not counts[1, 1]:
+            src_acc = atk_succ = nan
+        elif watch_target is None:
+            atk_succ = nan
+        return g_acc, nan, src_acc, atk_succ
 
 
 # 2-layer decoder-only transformer, small enough that a full federated
@@ -210,6 +214,20 @@ class MnistTask(FeelTask):
 LM_TINY = ModelConfig(name="lm-tiny", family="dense", n_layers=2,
                       d_model=64, n_heads=4, n_kv_heads=2, d_ff=128,
                       vocab_size=64, dtype="float32")
+
+
+@jax.jit
+def _mlp_global_counts(params, x, y, watch_class, watch_target):
+    """(3, 2) (correct, valid) counts of the MLP on the test set: every
+    unit; the watched class's units; those of them predicted as the
+    target (a class of -1 watches nothing)."""
+    pred = jnp.argmax(mlp_apply(params, x), -1)
+    correct = (pred == y).astype(jnp.float32)
+    watched = (y == watch_class).astype(jnp.float32)
+    return jnp.stack([
+        correct_counts(correct, jnp.ones_like(correct)),
+        correct_counts(correct, watched),
+        correct_counts((pred == watch_target).astype(jnp.float32), watched)])
 
 
 @partial(jax.jit, static_argnums=0)

@@ -9,6 +9,7 @@ import numpy as np
 import pytest
 
 from repro.configs.base import FeelConfig
+from repro.core import attacks as atk
 from repro.core.poisoning import EASY_PAIR, LabelFlipAttack, pick_malicious
 from repro.core.reputation import ReputationTracker
 from repro.data.partition import pad_clients, partition
@@ -167,6 +168,78 @@ def test_bucketed_k500_parity_and_padding_waste():
     np.testing.assert_allclose(curves[3], curves[1], atol=1e-5)
     assert wastes[3] < 1.25, wastes
     assert wastes[3] < wastes[1], wastes
+
+
+# ---------------------------------------------------------------------- #
+# The vectorized round's glue (one gather program, cohort_train per bucket,
+# one assemble program, cohort_eval, one host read) against the eager
+# composition it replaced, kept here as the oracle.
+# ---------------------------------------------------------------------- #
+def _k10_server(scenario):
+    cfg = _k10_cfg()
+    scn = atk.as_scenario(scenario)
+    train, test = generate(KW["n_train"], KW["n_test"], seed=0)
+    rng = np.random.default_rng(0)
+    mal = pick_malicious(cfg.n_ues, cfg.n_malicious, rng)
+    clients = partition(train, cfg.n_ues, rng, mal, scn.data)
+    return FeelServer(cfg, clients, test, rng, scenario=scn)
+
+
+def _eager_round(srv, sel, t):
+    """(padded uploads, acc_local, acc_test) of round ``t`` by the eager
+    composition: ``jnp.take`` per bucket, ``cohort_train``, each bucket's
+    real rows cut on the host, ``merge_stacks`` into selection order, the
+    attacks on the merged stack, ``pad_stacked``, ``cohort_eval``."""
+    parts = []
+    for bkt, pos, rows in srv._cohort_parts(sel, t):
+        idx = jnp.asarray(rows)
+        data = {f: jnp.take(a, idx, axis=0) for f, a in bkt["data"].items()}
+        stacked_b, acc_b = cohort.cohort_train(
+            srv.task, srv.params, data, jnp.take(bkt["mask"], idx, axis=0),
+            srv.lr, srv.cfg.local_epochs, srv.batch_size)
+        parts.append((pos,
+                      jax.tree.map(lambda l, m=pos.size: l[:m], stacked_b),
+                      count_accuracy(acc_b)[:pos.size]))
+    inv = np.argsort(np.concatenate([p[0] for p in parts]), kind="stable")
+    stacked = cohort.merge_stacks([p[1] for p in parts], inv)
+    acc_local = np.concatenate([p[2] for p in parts])[inv]
+    scn, mal = srv.scenario, srv._active_malicious(sel, t)
+    if scn.model is not None and mal.any():
+        stacked = scn.model.apply_stacked(stacked, srv.params, mal)
+    if scn.report is not None:
+        acc_local = scn.report.apply(acc_local, mal)
+    n_pad = cohort.pad_count(sel.size, srv._N_BUCKET)
+    stacked_p = cohort.pad_stacked(stacked, n_pad)
+    acc_test = count_accuracy(cohort.cohort_eval(
+        srv.task, stacked_p, srv._ex, srv._ey,
+        srv._eval_masks(sel, n_pad)))[:sel.size]
+    return stacked_p, acc_local, acc_test
+
+
+@pytest.mark.parametrize("scenario", ["flip_6to2", "flip_6to2_int2",
+                                      "sign_flip", "lying_flip_8to4"])
+def test_vectorized_round_matches_eager_composition(scenario):
+    """Uploads, acc_local and acc_test of three K=10 rounds are bit-equal
+    to the eager composition's, under a data attack (always on, and
+    scheduled: clean twin rows), a model attack and a report attack."""
+    srv = _k10_server(scenario)
+    active = 0
+    for t in range(3):
+        values, sched, sel, forced = srv._schedule_round(t)
+        want_up, want_local, want_test = _eager_round(srv, sel, t)
+        uploads, weights, acc_local, acc_test, acc_val = \
+            srv._train_cohort(sel, t)
+        for a, b in zip(jax.tree.leaves(uploads), jax.tree.leaves(want_up)):
+            np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+        np.testing.assert_array_equal(acc_local, want_local)
+        np.testing.assert_array_equal(acc_test, want_test)
+        active += int(srv._active_malicious(sel, t).sum())
+        srv._aggregate_uploads(sel, uploads, weights)
+        g_acc, g_loss, src_acc, atk_succ = srv._global_metrics()
+        srv._finalize_round(t, values, sched, sel, forced, acc_local,
+                            acc_test, g_acc, src_acc, atk_succ, acc_val,
+                            g_loss)
+    assert active > 0          # the attacks had rows to act on
 
 
 # ---------------------------------------------------------------------- #

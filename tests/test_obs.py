@@ -370,6 +370,77 @@ def test_compile_counter_hears_a_control_kernel_and_stops_when_off():
 
 
 # ---------------------------------------------------------------------- #
+# 4c. a warm vectorized round: two host reads, a handful of programs
+# ---------------------------------------------------------------------- #
+def _k10_vectorized_server():
+    from repro.core import attacks as atk
+    from repro.core.poisoning import pick_malicious
+    from repro.data.partition import partition
+    from repro.data.synthetic_mnist import generate
+    from repro.federated.server import FeelServer
+    cfg = FeelConfig(n_ues=10, n_malicious=2)
+    scn = atk.as_scenario("flip_6to2")
+    train, test = generate(3000, 400, seed=0)
+    rng = np.random.default_rng(0)
+    mal = pick_malicious(cfg.n_ues, cfg.n_malicious, rng)
+    clients = partition(train, cfg.n_ues, rng, mal, scn.data)
+    return FeelServer(cfg, clients, test, rng, scenario=scn)
+
+
+def _programs_in_spans(xplane: str, names) -> int:
+    """Programs the CPU client executed (``PjRtCpuExecutable::Execute``
+    events) inside the profiled spans called ``names``."""
+    from jax.profiler import ProfileData
+    spans, runs = [], []
+    for plane in ProfileData.from_file(xplane).planes:
+        if plane.name != "/host:CPU":
+            continue
+        for line in plane.lines:
+            for ev in line.events:
+                iv = (ev.start_ns, ev.start_ns + ev.duration_ns)
+                if ev.name in names:
+                    spans.append(iv)
+                elif ev.name == "PjRtCpuExecutable::Execute":
+                    runs.append(iv)
+    assert spans, "the profile holds none of the spans"
+    return sum(any(a <= s and e <= b for a, b in spans) for s, e in runs)
+
+
+def test_warm_vectorized_round_reads_twice_and_runs_few_programs(tmp_path):
+    """A warm K=10 round reads the chip twice (the cohort's local and
+    test counts together, then the global metrics) and runs at most 12
+    programs in its ``train`` and ``eval.global`` spans: a gather, one
+    ``cohort_train`` per bucket, the assembly, ``cohort_eval`` and the
+    global metrics — no eager glue."""
+    import glob
+
+    import jax
+    srv = _k10_vectorized_server()
+    initial = (srv.params, srv.reputation.values.copy(), srv.ages.copy(),
+               srv.rng.bit_generator.state)
+
+    def restore():
+        srv.params, rep, ages, state = initial
+        srv.reputation.values[:] = rep
+        srv.ages[:] = ages
+        srv.rng.bit_generator.state = state
+
+    srv.run_round(0)                 # compiles every program of round 0
+    restore()
+    trace.configure(enabled=True)
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    with jax.profiler.trace(str(tmp_path), profiler_options=opts):
+        srv.run_round(0)
+    counters = trace.tracer().metrics.snapshot()["counters"]
+    assert counters["data.host_reads"] == 2
+    (xplane,) = glob.glob(str(tmp_path / "**" / "*.xplane.pb"),
+                          recursive=True)
+    n = _programs_in_spans(xplane, ("train", "eval.global"))
+    assert 0 < n <= 12, n
+
+
+# ---------------------------------------------------------------------- #
 # 5. metrics registry + bench-writer integration
 # ---------------------------------------------------------------------- #
 def test_metric_registry_snapshot():
