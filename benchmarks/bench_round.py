@@ -28,8 +28,8 @@ cohort engine, ``--buckets`` levels), ``unbucketed`` (vectorized with a
 single global pad — the pre-bucketing baseline).
 
 ``--sweep`` instead measures a (policies x seeds) study end-to-end:
-batched ``run_sweep`` vs the same grid as sequential ``run_experiment``
-calls (each mode in a fresh subprocess).
+``run_sweep`` (shared dataset/partition/layout caches) vs the same grid
+as separate ``run_experiment`` calls (each mode in a fresh subprocess).
 
 ``--control`` measures the control plane alone — the per-round schedule
 phase (Eq. 2/3 values -> Eq. 9 costs -> policy selection) of a
@@ -43,8 +43,8 @@ to ``results/BENCH_control.json`` (the control-plane perf trajectory).
 per-malicious-client ``.at[i].set`` dispatch loop at growing n_malicious
 (bit-equality asserted; the masked path must be flat, the loop linear),
 plus a 4-scenario heterogeneous ``run_sweep`` (label flip, feature noise,
-free-rider, sign-flip) stacked vs sequential — written to
-``results/BENCH_attacks.json``.
+free-rider, sign-flip) vs each scenario's separate ``run_experiment`` —
+written to ``results/BENCH_attacks.json``.
 
 ``--defenses`` measures the defense plane: every robust aggregator
 (trimmed mean, median, norm clip, Krum) applied to a K-row stacked update
@@ -370,21 +370,25 @@ if mode == "apply":
                      "masked_ms": round(t_masked, 3)})
     print(json.dumps({"apply": rows}))
 else:
-    # heterogeneous scenario sweep: 4 distinct threat models, stacked in
-    # ONE run_sweep vs sequential (fresh subprocess per mode, cold jit —
-    # the same methodology as --sweep); accs returned for the parent's
-    # cross-mode divergence assertion.
-    from repro.federated.simulation import run_sweep
+    # heterogeneous scenario sweep: 4 distinct threat models in ONE
+    # run_sweep vs one run_experiment each (fresh subprocess per mode, cold
+    # jit — the same methodology as --sweep); accs returned for the
+    # parent's cross-mode divergence assertion.
+    from repro.federated.simulation import run_experiment, run_sweep
 
     n_train, rounds = int(sys.argv[2]), int(sys.argv[3])
     scns = ["flip_6to2", "noise_0.8", "free_rider", "sign_flip"]
     n_test = max(n_train // 10, 200)
     t0 = time.perf_counter()
-    res = run_sweep(["dqs"], seeds=[0], scenarios=scns, n_train=n_train,
-                    n_test=n_test, rounds=rounds,
-                    stack_runs=(mode == "sweep_stacked"))
+    if mode == "sweep":
+        accs = [r["acc"] for r in run_sweep(
+            ["dqs"], seeds=[0], scenarios=scns, n_train=n_train,
+            n_test=n_test, rounds=rounds).runs]
+    else:
+        accs = [run_experiment("dqs", scenario=s, seed=0, n_train=n_train,
+                               n_test=n_test, rounds=rounds)["acc"]
+                for s in scns]
     el = time.perf_counter() - t0
-    accs = [r["acc"] for r in res.runs]
     assert all(np.isfinite(a).all() for a in map(np.asarray, accs))
     print(json.dumps({"s_total": round(el, 2), "n_scenarios": len(scns),
                       "accs": accs}))
@@ -648,7 +652,7 @@ def bench_attacks(n_rows=64, reps=50, n_train=4000, rounds=3,
     vs the replaced per-malicious-client ``.at[i].set`` dispatch loop at
     growing n_malicious (bit-equality asserted in the worker — the masked
     path must be flat in n_malicious, the loop linear), and (2) a
-    4-scenario heterogeneous sweep, stacked vs sequential.
+    4-scenario heterogeneous sweep vs one run_experiment per scenario.
 
     The JSON artifact (results/BENCH_attacks.json) is only written for
     the canonical default sizes."""
@@ -659,18 +663,17 @@ def bench_attacks(n_rows=64, reps=50, n_train=4000, rounds=3,
               f"{r['masked_ms']:.3f},"
               f"{r['loop_ms'] / r['masked_ms']:.2f}", flush=True)
     res = {m: _run_worker(_ATTACKS_WORKER, [m, n_train, rounds])
-           for m in ("sweep_stacked", "sweep_sequential")}
-    for a, b in zip(res["sweep_stacked"]["accs"],
-                    res["sweep_sequential"]["accs"]):
+           for m in ("sweep", "sequential")}
+    for a, b in zip(res["sweep"]["accs"], res["sequential"]["accs"]):
         assert np.allclose(a, b, atol=1e-7), \
-            "stacked/sequential scenario-sweep divergence"
-    sw = {"n_scenarios": res["sweep_stacked"]["n_scenarios"],
-          "stacked_s": res["sweep_stacked"]["s_total"],
-          "sequential_s": res["sweep_sequential"]["s_total"]}
-    print("attacks_sweep,n_scenarios,stacked_s,sequential_s,speedup")
-    print(f"attacks_sweep,{sw['n_scenarios']},{sw['stacked_s']:.2f},"
+            "sweep/sequential scenario divergence"
+    sw = {"n_scenarios": res["sweep"]["n_scenarios"],
+          "sweep_s": res["sweep"]["s_total"],
+          "sequential_s": res["sequential"]["s_total"]}
+    print("attacks_sweep,n_scenarios,sweep_s,sequential_s,speedup")
+    print(f"attacks_sweep,{sw['n_scenarios']},{sw['sweep_s']:.2f},"
           f"{sw['sequential_s']:.2f},"
-          f"{sw['sequential_s'] / sw['stacked_s']:.2f}", flush=True)
+          f"{sw['sequential_s'] / sw['sweep_s']:.2f}", flush=True)
     out["sweep"] = sw
     if write_json:
         write_bench_json("attacks",
@@ -1075,7 +1078,7 @@ def smoke():
     ctl_rows = bench_control([50], n_runs=6, rounds=3, write_json=False)
     assert all(r["host_ms"] > 0 and r["batched_ms"] > 0 for r in ctl_rows)
     # threat-model plane: the worker asserts masked == per-client-loop
-    # attack application bitwise and stacked == sequential scenario sweep
+    # attack application bitwise and sweep == sequential scenario runs
     atk_out = bench_attacks(n_rows=16, reps=3, n_train=2500, rounds=2,
                             write_json=False)
     assert all(r["masked_ms"] > 0 for r in atk_out["apply"])

@@ -33,10 +33,9 @@ PAIRS = [("easy_6to2", (6, 2)), ("hard_8to4", (8, 4))]
 
 
 def curves(policies, scenario, omega, cfg, seeds, **kw):
-    """One batched sweep over (policies x seeds) of one threat scenario;
-    per-policy seed-averaged summaries. All seeds (and policies) of a
-    setting run as stacked cohorts — one vmapped train/eval call per size
-    bucket per round."""
+    """One sweep over (policies x seeds) of one threat scenario;
+    per-policy seed-averaged summaries. The runs of a setting share each
+    seed's dataset, partition and device layout."""
     res = run_sweep(policies, seeds=seeds, scenarios=[scenario], cfg=cfg,
                     omega=omega, **kw)
     out = {}
@@ -80,8 +79,8 @@ def main():
     ap.add_argument("--engine", choices=["vectorized", "loop"],
                     default="vectorized",
                     help="cohort execution engine (the vectorized engine + "
-                         "run_sweep batching make this multi-seed study "
-                         "feasible; 'loop' is the sequential oracle)")
+                         "run_sweep's shared caches make this multi-seed "
+                         "study feasible; 'loop' is the sequential oracle)")
     args = ap.parse_args()
     if args.fast:
         kw = dict(n_train=12_000, n_test=2_000, rounds=8)
@@ -112,7 +111,7 @@ def main():
                 results[key] = curve("dqs", _flip(pair), omega, cfg,
                                      seeds, **kw)
                 print(f"{key}: {results[key]['acc']}")
-        # baselines for context — one batched sweep over all three policies
+        # baselines for context — one sweep over all three policies
         base = curves(["random", "best_channel", "max_count"], _flip(pair),
                       (0.5, 0.5), FeelConfig(model_size_bits=5e6 * 8),
                       seeds, **kw)

@@ -4,9 +4,9 @@ core/attacks.py — model poisoning (sign-flip / boosted), free-riders
 (zero and stale updates), dishonest reporting on top of a label flip,
 feature noise, and intermittent / colluding malicious schedules — runs
 against DQS and the random baseline, each cell undefended AND under the
-``trimmed_mean+validation`` defense (core/defenses.py), as ONE stacked
-``run_sweep`` (scenarios and defenses are just more slices of the batched
-cohort + control planes). Plus the original adaptive-omega and K=100
+``trimmed_mean+validation`` defense (core/defenses.py), as ONE
+``run_sweep`` (scenarios and defenses are just more sweep axes, sharing
+partitions and device layouts). Plus the original adaptive-omega and K=100
 scale studies.
 
 The headline question (DESIGN.md §8 -> §9): does the validation detector
@@ -111,10 +111,10 @@ def main():
     results = {}
     t0 = time.time()
 
-    # 1) the whole threat-model x defense matrix in ONE stacked sweep:
-    # 9 scenarios x 2 defenses x 2 policies x 2 seeds = 72 runs,
-    # scheduled by one batched control-plane call per round, trained as
-    # stacked cohorts, partitions shared across the defense axis
+    # 1) the whole threat-model x defense matrix in ONE sweep:
+    # 9 scenarios x 2 defenses x 2 policies x 2 seeds = 72 runs, each
+    # through FeelServer.run_round, partitions shared across the defense
+    # axis
     defenses = ["none", "trimmed_mean+validation"]
     res = run_sweep(["dqs", "random"], seeds=seeds,
                     scenarios=SCENARIO_MATRIX, defenses=defenses,

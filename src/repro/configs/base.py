@@ -326,8 +326,7 @@ class FeelConfig:
     # default task (federated/task.py registry name): the model/data pair
     # the federated round trains — "mnist_mlp" (the paper's §V protocol)
     # or "lm_tiny" (federated LM fine-tuning). Sweeps vary tasks per run
-    # via ``run_sweep(tasks=[...])``; the batched control plane treats
-    # configs differing only in ``task`` as compatible (core/control.py).
+    # via ``run_sweep(tasks=[...])``, which sets this field per run.
     task: str = "mnist_mlp"
     # --- execution mode (federated/async_engine.py, DESIGN.md §13) ---
     # "sync" runs Alg. 1 as lockstep rounds; "async" runs the

@@ -106,8 +106,8 @@ class ControlState:
     def from_servers(cls, servers: Sequence) -> "ControlState":
         cfg = servers[0].cfg
         # the control plane never touches the data/model plane, so configs
-        # differing ONLY in ``task`` are compatible — a mixed-task sweep
-        # (run_sweep(tasks=[...])) schedules every run through one kernel
+        # differing ONLY in ``task`` are compatible (each FeelServer builds
+        # its own one-run state; the pool drivers stack runs directly)
         assert all(dataclasses.replace(s.cfg, task=cfg.task) == cfg
                    for s in servers), \
             "batched control requires one shared FeelConfig across runs " \

@@ -42,10 +42,7 @@ call whose weights span all buckets. The server's round runs that glue
 as two programs around the buckets' ``cohort_train`` calls:
 ``gather_buckets`` gathers every bucket's rows, and ``assemble_cohort``
 merges the trained rows into selection order, pads the cohort and
-gathers its eval masks. ``cohort_train_multi`` is the
-multi-run variant (per-row parameters) used by the batched sweep runner in
-``federated/simulation.py`` — seeds/policies become one more slice of the
-client axis.
+gathers its eval masks.
 
 Streamed cohort: a model too large to hold one copy per cohort row (the
 Moonlight share, 2.27 GB a copy) trains one client at a time instead.
@@ -91,28 +88,6 @@ def cohort_train(task, params, data, mask, lr, epochs: int,
         return p, task.local_metric(p, di, mi)
 
     return jax.vmap(one)(data, mask)
-
-
-@partial(jax.jit, static_argnames=("task", "epochs", "batch_size"))
-def cohort_train_multi(task, stacked_params, data, mask, lr, epochs: int,
-                       batch_size: int = 50):
-    """``cohort_train`` with *per-client* parameters (leaves (N, ...)).
-
-    The batched sweep runner's entry point: rows gathered from different
-    runs (policy x seed x attack-pair) carry different global models, so the
-    run axis folds into the client vmap axis — one compiled program trains
-    an arbitrary mix of runs as long as the padded (N, S) shape matches.
-    Row results are independent, so a row trains identically whether its
-    run's cohort is stacked alone or with other runs.
-    """
-    def one(p, di, mi):
-        q = jax.lax.fori_loop(
-            0, epochs,
-            lambda _, r: task.sgd_epoch(r, di, mi, lr, batch_size),
-            p)
-        return q, task.local_metric(q, di, mi)
-
-    return jax.vmap(one)(stacked_params, data, mask)
 
 
 def pad_count(n: int, multiple: int = 8) -> int:
@@ -190,7 +165,7 @@ def assemble_cohort(stacks, counts, take, masks, mask_rows):
 
 
 def broadcast_params(params, n: int):
-    """Tile a single parameter pytree to (n, ...) rows (sweep stacking)."""
+    """Tile a single parameter pytree to (n, ...) rows."""
     return jax.tree.map(lambda p: jnp.broadcast_to(p, (n,) + p.shape),
                         params)
 
@@ -212,23 +187,6 @@ def cohort_eval(task, stacked_params, eval_inputs, y_units, masks):
         return correct_counts(correct, m)
 
     return jax.vmap(one)(stacked_params, masks)
-
-
-@partial(jax.jit, static_argnames=("task",))
-def cohort_eval_rows(task, stacked_params, eval_inputs, y_rows, masks):
-    """``cohort_eval`` with per-row labels: y_rows (N, U).
-
-    The sweep's metric phase uses it to score the attack success rate —
-    a row whose unit labels are relabelled to the attack's target over
-    the source mask — alongside the plain accuracy rows, in the same
-    vmapped call.
-    """
-    def one(p, yr, m):
-        correct = (task.predict_units(p, eval_inputs)
-                   == yr).astype(jnp.float32)
-        return correct_counts(correct, m)
-
-    return jax.vmap(one)(stacked_params, y_rows, masks)
 
 
 def unstack(stacked_params, i: int):
@@ -323,16 +281,14 @@ def client_train(task, params, batches, lr, epochs: int):
 # ---------------------------------------------------------------------- #
 # telemetry probe surface (DESIGN.md §14)
 # ---------------------------------------------------------------------- #
-# The jitted entry points of the data plane. The sweep/serve drivers
+# The jitted entry points of the data plane. The experiment drivers
 # snapshot their compile-cache sizes (obs.trace.jit_cache_size) as
 # compile-cache gauges at end of run.
 JITTED_ENTRY_POINTS = {
     "cohort_train": cohort_train,
-    "cohort_train_multi": cohort_train_multi,
     "gather_buckets": gather_buckets,
     "assemble_cohort": assemble_cohort,
     "cohort_eval": cohort_eval,
-    "cohort_eval_rows": cohort_eval_rows,
     "client_sgd_step": client_sgd_step,
     "client_metric": client_metric,
     "upload_eval": upload_eval,

@@ -38,9 +38,8 @@ and raise there.
 
 The padded device-resident client arrays live in a ``CohortData`` that can
 be shared by several servers running on the same (dataset, partition) —
-the batched sweep runner (federated/simulation.py::run_sweep) builds it
-once per (seed, data-attack) and fans it out across policies and across
-the scenarios that share the same poisoned data.
+the sweep driver (federated/simulation.py::run_sweep) builds it once per
+(task, seed, data-attack) and hands it to every run on that partition.
 
 Threat model: the server takes an ``core.attacks.AttackScenario``; its
 model/report components apply to the merged cohort stack through ONE
@@ -205,10 +204,9 @@ class FeelServer:
     engine: 'vectorized' | 'loop' (see module docstring).
     control: 'batched' | 'host' — the control plane (values -> Eq. 9 costs
     -> Alg. 2 selection -> Eq. 1 reputation). 'batched' (default) runs it
-    as the jitted vmapped kernel of core/control.py (one run here; the
-    sweep runner stacks ALL its runs into the same kernel); 'host' is the
-    sequential numpy oracle (tests/test_control.py pins the parity) —
-    mirroring the engine='loop' pattern of the data plane.
+    as the jitted vmapped kernel of core/control.py (one run: R=1);
+    'host' is the sequential numpy oracle (tests/test_control.py pins
+    the parity) — mirroring the engine='loop' pattern of the data plane.
     n_buckets: number of max_samples size buckets for the vectorized
     engine (1 = the old single global pad; 2-3 reclaim the padding waste).
     scenario: an ``core.attacks.AttackScenario`` (or registry name) — the
@@ -225,12 +223,10 @@ class FeelServer:
     round and feeds a trust penalty into Eq. 1 in ``_finalize_round``.
     None defers to ``cfg.defense`` (default ``"none"``).
 
-    The underscore round-phase methods (_schedule_round, _cohort_parts,
-    _apply_attacks, _eval_masks, _aggregate_cohort,
-    _finalize_round, _log_round, draw_control_inputs) are a semi-public
-    contract: the batched sweep runner (federated/simulation.py)
-    interleaves them across runs — change their signatures and the sweep
-    changes with them.
+    Outside ``run_round``, ``AsyncFeelEngine`` composes the round-phase
+    methods, and the benchmark's drivers and faults wrap
+    ``_schedule_round``, ``_schedule_round_batched``, ``_train_cohort``
+    and ``_aggregate_uploads``.
     """
 
     _N_BUCKET = 8   # cohort sizes are padded to a multiple of this with
@@ -355,8 +351,7 @@ class FeelServer:
         # vectorized-engine client layout: injected (sweep-shared) or built
         # lazily on first use (see CohortData)
         self._cohort_data = cohort_data
-        # batched-control state (R=1): built lazily; the sweep runner builds
-        # its own R=n_runs ControlState instead and never touches this one
+        # batched-control state (R=1), built lazily
         self._ctrl: Optional[ctl.ControlState] = None
         # async-engine busy mask (federated/async_engine.py, DESIGN.md §13):
         # when set, these UEs have an upload in flight and must not be
@@ -487,19 +482,18 @@ class FeelServer:
                 n_buckets=self.n_buckets)
         return self._cohort_data
 
-    def _cohort_parts(self, sel: np.ndarray, t: int, pad: bool = True):
+    def _cohort_parts(self, sel: np.ndarray, t: int):
         """Split round ``t``'s cohort per size bucket.
 
         Yields ``(bucket, positions_in_sel, row_ids)``. A malicious UE
         whose data attack is INACTIVE in round t (round-scheduled
         scenarios) maps to its clean twin row instead of its poisoned row
         (see CohortData) — for always-on schedules the mapping is the
-        identity, bit-for-bit. With ``pad`` the row ids are padded to a
-        multiple of _N_BUCKET with the bucket's null client (mask all-zero
-        -> training no-op, weight 0 downstream), so rounds with new cohort
-        sizes reuse the compiled per-bucket step instead of re-tracing —
-        the exact pathology this engine replaces. The sweep runner passes
-        ``pad=False`` and pads the cross-run batch once instead.
+        identity, bit-for-bit. The row ids are padded to a multiple of
+        _N_BUCKET with the bucket's null client (mask all-zero -> training
+        no-op, weight 0 downstream), so rounds with new cohort sizes reuse
+        the compiled per-bucket step instead of re-tracing — the exact
+        pathology this engine replaces.
         """
         cd = self._ensure_cohort_data()
         rows_of = cd.row_of
@@ -513,11 +507,9 @@ class FeelServer:
             if pos.size == 0:
                 continue
             rows = rows_of[sel[pos]]
-            if pad:
-                n_pad = cohort.pad_count(pos.size, self._N_BUCKET)
-                rows = np.concatenate(
-                    [rows, np.full(n_pad - pos.size, bkt["null"],
-                                   rows.dtype)])
+            n_pad = cohort.pad_count(pos.size, self._N_BUCKET)
+            rows = np.concatenate(
+                [rows, np.full(n_pad - pos.size, bkt["null"], rows.dtype)])
             yield bkt, pos, rows
 
     def _active_malicious(self, sel: np.ndarray, t: int) -> np.ndarray:
@@ -584,13 +576,6 @@ class FeelServer:
             return acc_local
         return scn.report.apply(acc_local, self._active_malicious(sel, t))
 
-    def _eval_masks(self, sel: np.ndarray, n_pad: int) -> jax.Array:
-        """(n_pad, T) per-UE eval masks for the padded merged stack."""
-        cd = self._ensure_cohort_data()
-        idx = jnp.asarray(np.concatenate(
-            [sel, np.full(n_pad - sel.size, len(self.clients), sel.dtype)]))
-        return jnp.take(cd.mask_dev, idx, axis=0)
-
     def _cohort_weights(self, sel: np.ndarray, stacked_p) -> np.ndarray:
         """FedAvg sample-count weights for a padded merged stack: real rows
         carry their dataset size, pad rows weight 0."""
@@ -600,17 +585,13 @@ class FeelServer:
         return weights
 
     def _aggregate_cohort(self, sel: np.ndarray, stacked_p,
-                          weights: Optional[np.ndarray] = None) -> None:
+                          weights: np.ndarray) -> None:
         """ONE fedavg_stacked call whose weights span all buckets — or,
         under a defense with a robust aggregator, the batched defended
         aggregation over the padded (K_pad, P) flattened-update layout
         (core/defenses.py, DESIGN.md §9; stats land in ``_def_stats``
-        for ``_log_round``). ``weights`` overrides the sample-count
-        weights (the async engine passes staleness-discounted ones);
-        None computes them — callers like the stacked sweep runner stay
-        on the 2-arg form."""
-        if weights is None:
-            weights = self._cohort_weights(sel, stacked_p)
+        for the round's log). ``weights`` are the sample-count weights,
+        or the async engine's staleness-discounted ones."""
         agg = self.defense.aggregator
         with trace.span("defense.aggregate") as sp:
             if trace.enabled():
@@ -815,9 +796,8 @@ class FeelServer:
         return total, None, acc_local, acc_test, None
 
     # ------------------------------------------------------------------ #
-    # Round phases. ``run_round`` composes them; the batched sweep runner
-    # (federated/simulation.py) interleaves the phases of many runs so
-    # training/evaluation batch across runs.
+    # Round phases. ``run_round`` composes them; so does the async engine
+    # (federated/async_engine.py), on its own trigger.
     # ------------------------------------------------------------------ #
     def _schedule_round(self, t: int):
         """Alg. 1 lines 4-8: values -> schedule -> participant set.
@@ -864,7 +844,7 @@ class FeelServer:
             self._ctrl = ctl.ControlState.from_servers([self])
         return self._ctrl
 
-    def draw_control_inputs(self) -> Tuple[np.ndarray, np.ndarray]:
+    def _draw_control_inputs(self) -> Tuple[np.ndarray, np.ndarray]:
         """(gains, rand_rank) for one round, drawn from THIS server's RNG
         in the oracle's order: channel draw first, then — only for the
         ``random`` policy — the packing permutation. The batched kernel is
@@ -881,7 +861,7 @@ class FeelServer:
     def _schedule_round_batched(self, t: int):
         st = self._control_state()
         st.pull([self])
-        gains, rand_rank = self.draw_control_inputs()
+        gains, rand_rank = self._draw_control_inputs()
         w_rep, w_div = self._omega(t)
         if self.cfg.population is not None:
             # population cut: schedule through the top-M prefilter
@@ -944,7 +924,7 @@ class FeelServer:
         """Validation-detector phase: anomaly scores -> Eq. 1 trust
         penalties (returned, aligned with ``sel``) + detection metrics
         against the ground-truth malicious mask (merged into
-        ``_def_stats`` for ``_log_round`` — metrics only)."""
+        ``_def_stats`` for the round's log — metrics only)."""
         det = self.defense.detector
         if det is None or acc_val is None or sel.size == 0:
             return None
@@ -979,31 +959,24 @@ class FeelServer:
                 # ages: selected reset, others grow (staleness of Eq. 2)
                 self.ages += 1.0
                 self.ages[sel] = 1.0
-            return self._log_round(t, values, sched, sel, forced, g_acc,
-                                   src_acc, atk_succ, g_loss)
-
-    def _log_round(self, t: int, values, sched, sel, forced, g_acc,
-                   src_acc, atk_succ=float("nan"),
-                   g_loss=float("nan")) -> RoundLog:
-        """Append the RoundLog for a finalized round (reputation/ages
-        already updated — the batched sweep runner updates ALL runs in one
-        ``control.finalize_runs`` call and then logs per run)."""
-        ds = self._def_stats
-        log = RoundLog(
-            round=t, selected=sel, global_acc=g_acc, global_loss=g_loss,
-            n_malicious_selected=sum(self.clients[k].malicious for k in sel),
-            objective=0.0 if forced else sched.objective(),
-            values=values.copy(),
-            reputations=self.reputation.values.copy(), source_acc=src_acc,
-            attack_success=atk_succ,
-            rep_gap=atk.reputation_gap(self.reputation.values,
-                                       self._mal_mask),
-            forced=forced,
-            n_clipped=ds.n_clipped, n_rejected=ds.n_rejected,
-            n_flagged=ds.n_flagged, det_precision=ds.det_precision,
-            det_recall=ds.det_recall)
-        self.logs.append(log)
-        return log
+            ds = self._def_stats
+            log = RoundLog(
+                round=t, selected=sel, global_acc=g_acc,
+                global_loss=g_loss,
+                n_malicious_selected=sum(self.clients[k].malicious
+                                         for k in sel),
+                objective=0.0 if forced else sched.objective(),
+                values=values.copy(),
+                reputations=self.reputation.values.copy(),
+                source_acc=src_acc, attack_success=atk_succ,
+                rep_gap=atk.reputation_gap(self.reputation.values,
+                                           self._mal_mask),
+                forced=forced,
+                n_clipped=ds.n_clipped, n_rejected=ds.n_rejected,
+                n_flagged=ds.n_flagged, det_precision=ds.det_precision,
+                det_recall=ds.det_recall)
+            self.logs.append(log)
+            return log
 
     def _global_metrics(self) -> Tuple[float, float, float, float]:
         """(global unit accuracy, global loss, watch accuracy, attack
@@ -1016,12 +989,6 @@ class FeelServer:
                                             self._ex, self._ey,
                                             self.watch_class,
                                             self.watch_target)
-
-    def _global_loss(self) -> float:
-        """The task's global loss metric alone (the stacked sweep computes
-        accuracies through its batched eval and only needs this extra)."""
-        loss = self.task.eval_loss(self.params, self._ex)
-        return float("nan") if loss is None else float(loss)
 
     def run_round(self, t: int) -> RoundLog:
         with trace.span("round") as sp:

@@ -78,14 +78,14 @@ def test_scenario_parity_matrix(name, engine, control):
 
 def test_heterogeneous_scenario_sweep():
     """Acceptance: >= 4 distinct threat models (label flip, noise,
-    free-rider, model poison) run in ONE stacked sweep, each reproducing
-    its sequential oracle."""
+    free-rider, model poison) run in ONE sweep, each reproducing its
+    ``run_experiment`` twin."""
     scns = ["flip_6to2", "noise_0.8", "free_rider", "sign_flip"]
     res = run_sweep(["dqs"], seeds=[0], scenarios=scns, cfg=_cfg(), **KW)
-    seq = run_sweep(["dqs"], seeds=[0], scenarios=scns, cfg=_cfg(),
-                    stack_runs=False, **KW)
     assert [r["scenario"] for r in res.runs] == scns
-    for a, b in zip(res.runs, seq.runs):
+    for a in res.runs:
+        b = run_experiment("dqs", scenario=a["scenario"], cfg=_cfg(),
+                           seed=0, **KW)
         np.testing.assert_allclose(a["acc"], b["acc"], atol=1e-7)
         np.testing.assert_allclose(a["attack_success"],
                                    b["attack_success"], atol=1e-6)

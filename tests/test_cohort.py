@@ -210,9 +210,12 @@ def _eager_round(srv, sel, t):
         acc_local = scn.report.apply(acc_local, mal)
     n_pad = cohort.pad_count(sel.size, srv._N_BUCKET)
     stacked_p = cohort.pad_stacked(stacked, n_pad)
+    mask_rows = np.concatenate(
+        [sel, np.full(n_pad - sel.size, len(srv.clients), sel.dtype)])
+    masks = jnp.take(srv._ensure_cohort_data().mask_dev,
+                     jnp.asarray(mask_rows), axis=0)
     acc_test = count_accuracy(cohort.cohort_eval(
-        srv.task, stacked_p, srv._ex, srv._ey,
-        srv._eval_masks(sel, n_pad)))[:sel.size]
+        srv.task, stacked_p, srv._ex, srv._ey, masks))[:sel.size]
     return stacked_p, acc_local, acc_test
 
 

@@ -189,7 +189,7 @@ def test_run_experiment_control_parity():
 
 @pytest.mark.slow
 def test_full_sweep_control_parity():
-    """run_sweep with the stacked batched control plane reproduces the
+    """run_sweep with the batched control plane reproduces the
     host-control sweep run for run: same selections, curves, objectives."""
     from repro.federated.simulation import run_sweep
     a = run_sweep(["dqs", "max_count"], seeds=[0, 1],

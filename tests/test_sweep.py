@@ -1,9 +1,12 @@
-"""Batched multi-run sweep (run_sweep) vs the sequential run_experiment
-oracle, plus the tidy-table / averaged() API contracts."""
+"""Multi-run sweep (run_sweep) vs run_experiment, its one-run case: each
+run of a sweep equals its twin, the sweep's caches are shared across
+runs, and the tidy-table / averaged() API contracts hold."""
 import numpy as np
 import pytest
 
+from repro.core import attacks as atk
 from repro.core.poisoning import EASY_PAIR
+from repro.federated import simulation
 from repro.federated.simulation import (SweepResult, averaged,
                                         run_experiment, run_sweep)
 
@@ -17,11 +20,9 @@ def sweep() -> SweepResult:
 
 
 def test_sweep_matches_sequential_run_experiment(sweep):
-    """Every run of the stacked sweep must reproduce its sequential
-    ``run_experiment`` twin: same RNG streams, same schedules, same
-    accuracy curves. Global accuracy matches to float32 exactness;
-    source_acc is a masked-sum instead of a subset-mean, so it is equal
-    to ~1 ulp."""
+    """Every run of the sweep must reproduce its ``run_experiment`` twin:
+    same RNG streams, same schedules, same accuracy curves, although the
+    sweep shares its dataset, partition and layout caches across runs."""
     for run in sweep.runs:
         ref = run_experiment(run["policy"], run["attack_pair"],
                              seed=run["seed"], **KW)
@@ -45,16 +46,36 @@ def test_sweep_matches_sequential_run_experiment(sweep):
             ref["final_reputation_malicious"], atol=1e-7)
 
 
-def test_stacked_matches_unstacked_sweep(sweep):
-    """stack_runs=False (sequential execution, shared caches) is the
-    oracle for the cross-run stacked path."""
-    seq = run_sweep(["dqs", "random"], seeds=[0, 1],
-                    attack_pairs=[EASY_PAIR], stack_runs=False, **KW)
-    assert len(seq.runs) == len(sweep.runs)
-    for a, b in zip(sweep.runs, seq.runs):
-        assert (a["policy"], a["seed"]) == (b["policy"], b["seed"])
-        np.testing.assert_allclose(a["acc"], b["acc"], atol=1e-7)
-        assert a["malicious_selected"] == b["malicious_selected"]
+def test_sweep_shares_layouts_across_runs(monkeypatch):
+    """The sweep's caches: one device layout per (task, seed, data attack),
+    and every run on that partition is built with the same ``CohortData``
+    object. The two model-space scenarios share the clean partition."""
+    built, handed = [], []
+    build, server = simulation.build_cohort_data, simulation.FeelServer
+
+    def counting_build(*args, **kw):
+        built.append(build(*args, **kw))
+        return built[-1]
+
+    def recording_server(*args, **kw):
+        handed.append(kw["cohort_data"])
+        return server(*args, **kw)
+
+    monkeypatch.setattr(simulation, "build_cohort_data", counting_build)
+    monkeypatch.setattr(simulation, "FeelServer", recording_server)
+    res = run_sweep(["dqs", "random"], seeds=[0],
+                    scenarios=["free_rider", "sign_flip", "flip_6to2"],
+                    n_train=3000, n_test=400, rounds=1)
+    assert len(built) == 2
+    assert len(handed) == len(res.runs) == 6
+    by_data = {}
+    for run, cd in zip(res.runs, handed):
+        data_key = atk.as_scenario(run["scenario"]).data_key()
+        by_data.setdefault(data_key, []).append(cd)
+    assert len(by_data) == 2
+    for cds in by_data.values():
+        assert all(cd is cds[0] for cd in cds)
+    assert {id(cd) for cd in handed} == {id(cd) for cd in built}
 
 
 def test_sweep_tidy_table(sweep):
